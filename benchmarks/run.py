@@ -43,6 +43,8 @@ def main(argv=None) -> int:
                    help="also write the rows to this path as a JSON baseline")
     args = p.parse_args(argv)
     only = set(args.only.split(",")) if args.only else None
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     import importlib
     print("name,us_per_call,derived")

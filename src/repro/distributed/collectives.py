@@ -24,7 +24,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.distributed.compat import shard_map
+from jax import shard_map
 from repro.kernels import resolve_backend
 from repro.kernels.flash_decode.ops import flash_decode_partials
 from repro.kernels.flash_decode.ref import (decode_attention_reference,

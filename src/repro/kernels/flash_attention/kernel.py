@@ -69,8 +69,8 @@ def _q_index_maps(block_q: int, block_k: int, causal: bool):
         def qi_of(ki, qi):
             return jnp.maximum(qi, _first_q_block(ki, block_q, block_k))
         return (lambda b, ki, qi: (b, qi_of(ki, qi), 0),
-                lambda b, ki, qi: (b, qi_of(ki, qi)))
-    return (lambda b, ki, qi: (b, qi, 0), lambda b, ki, qi: (b, qi))
+                lambda b, ki, qi: (b, 0, qi_of(ki, qi)))
+    return (lambda b, ki, qi: (b, qi, 0), lambda b, ki, qi: (b, 0, qi))
 
 
 def _masked_scores(q, k, qi, ki, *, block_q, block_k, scale, causal,
@@ -138,7 +138,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr, *,
     def _finalize():
         l = jnp.maximum(l_scr[...], 1e-30)
         o_ref[0] = (acc_scr[...] / l).astype(o_ref.dtype)
-        lse_ref[0] = (m_scr[...] + jnp.log(l))[:, 0]
+        lse_ref[0, 0] = (m_scr[...] + jnp.log(l))[:, 0]
 
 
 def flash_attention_fwd(q: jax.Array, k: jax.Array, v: jax.Array, *,
@@ -148,8 +148,11 @@ def flash_attention_fwd(q: jax.Array, k: jax.Array, v: jax.Array, *,
                         ) -> tuple[jax.Array, jax.Array]:
     """q, k, v: (BH, S, D) (GQA repeat handled by ops.py).
 
-    Returns (o (BH, S, D), lse (BH, S) fp32).  `valid_len` masks padded tail
-    keys (0 = none).
+    Returns (o (BH, S, D), lse (BH, 1, S) fp32).  `valid_len` masks padded
+    tail keys (0 = none).  The per-row lse and delta are stored one row of
+    lanes per head, ``(BH, 1, S)`` with ``(1, 1, block_q)`` blocks: the chip
+    tiles a block's last two dimensions by (8, 128), and a ``(1, block_q)``
+    block of a ``(BH, S)`` array is not such a tile.
     """
     bh, s, d = q.shape
     block_q = min(block_q, s)
@@ -175,11 +178,11 @@ def flash_attention_fwd(q: jax.Array, k: jax.Array, v: jax.Array, *,
         ],
         out_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, qi, ki: (b, qi, 0)),
-            pl.BlockSpec((1, block_q), lambda b, qi, ki: (b, qi)),
+            pl.BlockSpec((1, 1, block_q), lambda b, qi, ki: (b, 0, qi)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((bh, s, d), q.dtype),
-            jax.ShapeDtypeStruct((bh, s), jnp.float32),
+            jax.ShapeDtypeStruct((bh, 1, s), jnp.float32),
         ],
         scratch_shapes=[
             _vmem((block_q, 1), jnp.float32),  # m: running row max
@@ -197,7 +200,7 @@ def flash_attention_fwd(q: jax.Array, k: jax.Array, v: jax.Array, *,
 def _bwd_preprocess_kernel(o_ref, do_ref, delta_ref):
     o = o_ref[0].astype(jnp.float32)
     do = do_ref[0].astype(jnp.float32)
-    delta_ref[0] = jnp.sum(o * do, axis=-1)
+    delta_ref[0, 0] = jnp.sum(o * do, axis=-1)
 
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
@@ -215,8 +218,8 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
         k = k_ref[0].astype(jnp.float32)
         v = v_ref[0].astype(jnp.float32)
         do = do_ref[0].astype(jnp.float32)
-        lse = lse_ref[0][:, None]      # (block_q, 1)
-        delta = delta_ref[0][:, None]  # (block_q, 1)
+        lse = lse_ref[0, 0][:, None]      # (block_q, 1)
+        delta = delta_ref[0, 0][:, None]  # (block_q, 1)
         s = _masked_scores(q, k, qi, ki, block_q=block_q, block_k=block_k,
                            scale=scale, causal=causal, valid_len=valid_len,
                            kv_len=kv_blocks * block_k)
@@ -257,8 +260,8 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref,
         k = k_ref[0].astype(jnp.float32)
         v = v_ref[0].astype(jnp.float32)
         do = do_ref[0].astype(jnp.float32)
-        lse = lse_ref[0][:, None]
-        delta = delta_ref[0][:, None]
+        lse = lse_ref[0, 0][:, None]
+        delta = delta_ref[0, 0][:, None]
         s = _masked_scores(q, k, qi, ki, block_q=block_q, block_k=block_k,
                            scale=scale, causal=causal, valid_len=valid_len,
                            kv_len=kv_blocks * block_k)
@@ -294,7 +297,7 @@ def flash_attention_bwd(q: jax.Array, k: jax.Array, v: jax.Array,
                         ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """Backward pass at the flattened (BH, S, D) layout.
 
-    q, k, v, o, do: (BH, S, D); lse: (BH, S) fp32 from the forward.
+    q, k, v, o, do: (BH, S, D); lse: (BH, 1, S) fp32 from the forward.
     Returns (dq, dk, dv) with the input dtypes.
     """
     bh, s, d = q.shape
@@ -314,15 +317,15 @@ def flash_attention_bwd(q: jax.Array, k: jax.Array, v: jax.Array,
             pl.BlockSpec((1, block_q, d), lambda b, qi: (b, qi, 0)),
             pl.BlockSpec((1, block_q, d), lambda b, qi: (b, qi, 0)),
         ],
-        out_specs=pl.BlockSpec((1, block_q), lambda b, qi: (b, qi)),
-        out_shape=jax.ShapeDtypeStruct((bh, s), jnp.float32),
+        out_specs=pl.BlockSpec((1, 1, block_q), lambda b, qi: (b, 0, qi)),
+        out_shape=jax.ShapeDtypeStruct((bh, 1, s), jnp.float32),
         interpret=interpret,
     )(o, do)
 
     # dQ: kv minor, online accumulation into VMEM scratch
     kv_map = _kv_index_map(block_q, block_k, causal)
     q_map3 = lambda b, qi, ki: (b, qi, 0)
-    q_row3 = lambda b, qi, ki: (b, qi)
+    q_row3 = lambda b, qi, ki: (b, 0, qi)
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, block_q=block_q, block_k=block_k,
                           scale=scale, causal=causal, kv_blocks=kv_blocks,
@@ -333,8 +336,8 @@ def flash_attention_bwd(q: jax.Array, k: jax.Array, v: jax.Array,
             pl.BlockSpec((1, block_k, d), kv_map),
             pl.BlockSpec((1, block_k, d), kv_map),
             pl.BlockSpec((1, block_q, d), q_map3),
-            pl.BlockSpec((1, block_q), q_row3),
-            pl.BlockSpec((1, block_q), q_row3),
+            pl.BlockSpec((1, 1, block_q), q_row3),
+            pl.BlockSpec((1, 1, block_q), q_row3),
         ],
         out_specs=pl.BlockSpec((1, block_q, d), q_map3),
         out_shape=jax.ShapeDtypeStruct((bh, s, d), q.dtype),
@@ -355,8 +358,8 @@ def flash_attention_bwd(q: jax.Array, k: jax.Array, v: jax.Array,
             pl.BlockSpec((1, block_k, d), kv_map2),
             pl.BlockSpec((1, block_k, d), kv_map2),
             pl.BlockSpec((1, block_q, d), q_clamp),
-            pl.BlockSpec((1, block_q), q_row_clamp),
-            pl.BlockSpec((1, block_q), q_row_clamp),
+            pl.BlockSpec((1, 1, block_q), q_row_clamp),
+            pl.BlockSpec((1, 1, block_q), q_row_clamp),
         ],
         out_specs=[
             pl.BlockSpec((1, block_k, d), kv_map2),

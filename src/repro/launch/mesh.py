@@ -14,7 +14,14 @@ from typing import Optional, Tuple
 
 import numpy as np
 
-from repro.distributed.compat import make_mesh
+
+def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...], devices):
+    """``jax.make_mesh`` with every axis Auto (the compiler propagates
+    shardings from the annotated inputs)."""
+    import jax
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):

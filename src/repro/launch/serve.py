@@ -21,6 +21,7 @@ from __future__ import annotations
 
 import argparse
 import json
+import sys
 import time
 
 import numpy as np
@@ -28,6 +29,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_arch, reduced as reduce_cfg
 from repro.data import SyntheticCorpus
 from repro.models import model_zoo
@@ -240,6 +242,10 @@ def serve_router(arch: str, use_reduced: bool, n_slots: int, prompt_len: int,
 
 
 def main(argv=None) -> int:
+    """Returns 1 when any request finished with an error or any replica
+    retired a slot on an error: the engine contains a failing request so
+    the batch keeps going, and the exit code is what reports it."""
+    enable_compile_cache()
     p = argparse.ArgumentParser(description=__doc__)
     p.add_argument("--arch", default="smollm-360m")
     p.add_argument("--reduced", action="store_true")
@@ -294,8 +300,9 @@ def main(argv=None) -> int:
     if args.legacy:
         serve(args.arch, args.reduced, args.batch, args.prompt_len, args.gen,
               cache_len=args.cache_len, seed=args.seed)
-    elif args.replicas > 1 or args.disaggregate:
-        serve_router(args.arch, args.reduced, args.batch, args.prompt_len,
+        return 0
+    if args.replicas > 1 or args.disaggregate:
+        out = serve_router(args.arch, args.reduced, args.batch, args.prompt_len,
                      args.gen, n_requests=args.requests,
                      cache_len=args.cache_len, seed=args.seed,
                      ragged=not args.uniform, sampling=sp,
@@ -304,8 +311,9 @@ def main(argv=None) -> int:
                      prefill_batch=args.prefill_batch, paged=args.paged,
                      page_size=args.page_size, n_pages=args.n_pages,
                      metrics_jsonl=args.metrics_jsonl)
+        slot_errors = out["summary"]["aggregate"]["slot_errors"]
     else:
-        serve_engine(args.arch, args.reduced, args.batch, args.prompt_len,
+        out = serve_engine(args.arch, args.reduced, args.batch, args.prompt_len,
                      args.gen, n_requests=args.requests,
                      cache_len=args.cache_len, seed=args.seed,
                      ragged=not args.uniform, sampling=sp,
@@ -313,6 +321,13 @@ def main(argv=None) -> int:
                      decode_backend=args.decode_backend, paged=args.paged,
                      page_size=args.page_size, n_pages=args.n_pages,
                      policy=args.policy, metrics_jsonl=args.metrics_jsonl)
+        slot_errors = out["stats"].slot_errors
+    failed = sum(r.finish_reason == "error" for r in out["results"])
+    if failed or slot_errors:
+        print(f"serve failed: {failed} of {len(out['results'])} requests "
+              f"finished with an error, slot_errors={slot_errors}",
+              file=sys.stderr)
+        return 1
     return 0
 
 

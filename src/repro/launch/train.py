@@ -47,6 +47,7 @@ from repro.core.recovery import (RecoveryConfig, RecoveryHook,
 from repro.core.regulators import (ControllerState, RegulatorStack, StepPlan,
                                    StepTelemetry, build_stack)
 from repro.checkpoint import CheckpointManager, migrate_host_state
+from repro.compile_cache import enable_compile_cache
 from repro.data import DataPipeline, SyntheticCorpus
 from repro.distributed.fault_injection import (FaultInjectionHook,
                                                FaultInjector)
@@ -612,7 +613,8 @@ def build_config(args) -> TrainConfig:
     return tc
 
 
-def main(argv=None) -> int:
+def build_parser() -> argparse.ArgumentParser:
+    """The train CLI's arguments (``build_config`` reads the parsed result)."""
     p = argparse.ArgumentParser(description=__doc__)
     p.add_argument("--arch", default="gpt2-117m")
     p.add_argument("--reduced", action="store_true",
@@ -700,8 +702,12 @@ def main(argv=None) -> int:
                         "stall@8:0.25' (kind@step[:arg], comma-separated)")
     p.add_argument("--inject-seed", type=int, default=0,
                    help="seed for fault placement (which leaf/byte)")
-    args = p.parse_args(argv)
+    return p
 
+
+def main(argv=None) -> int:
+    enable_compile_cache()
+    args = build_parser().parse_args(argv)
     tc = build_config(args)
     drain = DrainSignal()
     dp = args.dp_size or jax.device_count()

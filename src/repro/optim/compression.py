@@ -20,7 +20,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.distributed.compat import shard_map
+from jax import shard_map
 
 
 def compress(t: jax.Array) -> Tuple[jax.Array, jax.Array]:

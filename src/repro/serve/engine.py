@@ -35,6 +35,7 @@ batch composition — identical again under any router/policy/role split
 """
 from __future__ import annotations
 
+import logging
 import time
 from collections import deque
 from dataclasses import dataclass, field
@@ -56,6 +57,8 @@ from repro.serve.types import (GenerationResult, PrefillOutcome,
                                ReplicaTelemetry, Request)
 
 OnToken = Callable[[int, int], None]  # (request uid, token id)
+
+log = logging.getLogger(__name__)
 
 
 # per-step decode latency samples kept for percentiles: a bounded ring,
@@ -206,6 +209,8 @@ class EngineCore:
             toks = jnp.asarray([r.tokens[:split] for r in reqs], jnp.int32)
             logits, kcache = self._prefill(self.params, {"tokens": toks})
         except Exception:  # noqa: BLE001 — shared phase: all k rows fail
+            # a compile error lands here too: keep its traceback
+            log.exception("prefill of %d request(s) failed", len(reqs))
             for o in outcomes:
                 o.error = "prefill"
             return outcomes

@@ -1,0 +1,110 @@
+"""Compile the main-path Pallas kernels for a TPU v5e chip that is described,
+not attached.
+
+Nothing runs: each case lowers and compiles at real model widths with the
+chip's own compiler, which refuses what interpret mode accepts (block shapes
+that are not legal tiles, too much VMEM).  The topology is described inside
+a fixture, never at import: only one process at a time may load the TPU
+library, and every test worker imports this file.
+
+The ``xfail(strict=True)`` cases are kernels the compiler still refuses;
+each reason quotes the refusal, and a case that starts to compile fails
+until its mark is removed.
+"""
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels import (flash_attention, flash_decode, flash_decode_paged,
+                           ssd, wkv6)
+
+KERNEL_OP = "tpu_custom_call"
+BLOCK_RULE = ("last two dimensions of your block shape are divisible by 8 "
+              "and 128")
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("TPU_LOG_DIR", "disabled")  # else the library logs to /tmp
+        try:
+            topo = topologies.get_topology_desc(platform="tpu",
+                                                topology_name="v5e:2x2")
+        except Exception as e:  # noqa: BLE001 — no TPU library here
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _compile_for_chip(fn, *shapes):
+    """Compile ``fn`` at ``shapes`` for the described chip; assert the
+    Pallas kernel is in the program."""
+    compiled = jax.jit(fn).lower(*shapes).compile()
+    assert KERNEL_OP in compiled.as_text()
+    return compiled
+
+
+def _sds(sharding, shape, dtype=jnp.float32):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+# gpt2-117m: 12 heads of 64; SLW buckets below 128 and not multiples of 128
+# run the same kernel as the full 1024
+@pytest.mark.parametrize("direction", ["fwd", "grad"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("seq", [1024, 72])
+def test_flash_attention_compiles(one_chip, seq, dtype, direction):
+    def fwd(q, k, v):
+        return flash_attention(q, k, v, causal=True, interpret=False)
+
+    def loss(q, k, v):
+        return fwd(q, k, v).astype(jnp.float32).sum()
+
+    fn = fwd if direction == "fwd" else jax.grad(loss, argnums=(0, 1, 2))
+    x = _sds(one_chip, (8, seq, 12, 64), dtype)
+    _compile_for_chip(fn, x, x, x)
+
+
+def test_flash_decode_compiles(one_chip):
+    q = _sds(one_chip, (8, 12, 64))
+    cache = _sds(one_chip, (8, 2048, 12, 64))
+    lengths = _sds(one_chip, (8,), jnp.int32)
+    _compile_for_chip(
+        lambda q, k, v, n: flash_decode(q, k, v, n, interpret=False),
+        q, cache, cache, lengths)
+
+
+@pytest.mark.xfail(strict=True, raises=ValueError, reason=(
+    "zamba2-2.7b SSD: the rank-1 block (1,) over a_coef (H,) is refused; "
+    "rank-1 blocks must equal the array or be a multiple of 128"))
+def test_ssd_compiles_at_zamba2_widths(one_chip):
+    b, h, s, p, n = 1, 80, 1024, 64, 64  # d_model 2560 x expand 2 / 64
+    _compile_for_chip(
+        lambda *a: ssd(*a, interpret=False)[0],
+        _sds(one_chip, (b, h, s, p)), _sds(one_chip, (b, h, s)),
+        _sds(one_chip, (h,)), _sds(one_chip, (b, s, n)),
+        _sds(one_chip, (b, s, n)))
+
+
+@pytest.mark.xfail(strict=True, raises=ValueError, reason=(
+    "rwkv6-7b WKV6: the u block (1, 64) over (64, 64) is refused: "
+    + BLOCK_RULE))
+def test_wkv6_compiles_at_rwkv6_widths(one_chip):
+    x = _sds(one_chip, (1, 64, 1024, 64))  # 64 heads of 64
+    _compile_for_chip(lambda *a: wkv6(*a, interpret=False)[0],
+                      x, x, x, x, _sds(one_chip, (64, 64)))
+
+
+@pytest.mark.xfail(strict=True, raises=ValueError, reason=(
+    "zamba2-2.7b paged decode: the k/v page block (1, page, 1, 80) over the "
+    "(n_pages, page, 32, 80) pool is refused: " + BLOCK_RULE))
+def test_flash_decode_paged_compiles_at_zamba2_widths(one_chip):
+    slots, pages_per_slot, page = 8, 16, 128
+    pool = _sds(one_chip, (slots * pages_per_slot, page, 32, 80))
+    _compile_for_chip(
+        lambda *a: flash_decode_paged(*a, interpret=False),
+        _sds(one_chip, (slots, 32, 80)), pool, pool,
+        _sds(one_chip, (slots, pages_per_slot), jnp.int32),
+        _sds(one_chip, (slots,), jnp.int32))
