@@ -39,6 +39,8 @@ import traceback
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)),
                                 "src"))
 
+from bench.harness.spans import CompileClock  # noqa: E402
+
 ARCH = "gpt2-117m"
 TRAIN_ARGV = ("--arch", ARCH, "--batch", "8", "--seq", "1024",
               "--remat", "full", "--slw", "--start-seq", "8",
@@ -55,9 +57,6 @@ LOGITS_RTOL = 1e-3  # of the largest reference logit
 FIRST_LOSS_ATOL = 0.5  # around ln(vocab), the loss of a uniform guess
 
 KERNEL_OP = "tpu_custom_call"
-COMPILE_EVENTS = ("/jax/core/compile/jaxpr_trace_duration",
-                  "/jax/core/compile/jaxpr_to_mlir_module_duration",
-                  "/jax/core/compile/backend_compile_duration")
 
 
 class SmokeFailure(Exception):
@@ -67,28 +66,6 @@ class SmokeFailure(Exception):
 def check(ok: bool, what: str) -> None:
     if not ok:
         raise SmokeFailure(what)
-
-
-class CompileClock:
-    """Seconds JAX spent tracing, lowering and compiling, and the
-    persistent-cache hits and misses, from JAX's own monitoring events."""
-
-    def __init__(self, monitoring):
-        self.seconds = 0.0
-        self.cache_hits = 0
-        self.cache_misses = 0
-        monitoring.register_event_duration_secs_listener(self._duration)
-        monitoring.register_event_listener(self._event)
-
-    def _duration(self, event, secs, **_):
-        if event in COMPILE_EVENTS:
-            self.seconds += secs
-
-    def _event(self, event, **_):
-        if event == "/jax/compilation_cache/cache_hits":
-            self.cache_hits += 1
-        elif event == "/jax/compilation_cache/cache_misses":
-            self.cache_misses += 1
 
 
 def peak_bytes(dev) -> int:
@@ -312,7 +289,7 @@ def main(argv=None) -> int:
           f"count={count}")
     print(f"compile cache: {cache_dir}")
 
-    clock = CompileClock(jax.monitoring)
+    clock = CompileClock()
     t_start = time.perf_counter()
     phase = "train"
     try:

@@ -190,6 +190,7 @@ def flash_attention_fwd(q: jax.Array, k: jax.Array, v: jax.Array, *,
             _vmem((block_q, d), jnp.float32),  # acc: weighted values
         ],
         interpret=interpret,
+        name="flash_attention_fwd",
     )(q, k, v)
 
 
@@ -320,6 +321,7 @@ def flash_attention_bwd(q: jax.Array, k: jax.Array, v: jax.Array,
         out_specs=pl.BlockSpec((1, 1, block_q), lambda b, qi: (b, 0, qi)),
         out_shape=jax.ShapeDtypeStruct((bh, 1, s), jnp.float32),
         interpret=interpret,
+        name="flash_attention_delta",
     )(o, do)
 
     # dQ: kv minor, online accumulation into VMEM scratch
@@ -343,6 +345,7 @@ def flash_attention_bwd(q: jax.Array, k: jax.Array, v: jax.Array,
         out_shape=jax.ShapeDtypeStruct((bh, s, d), q.dtype),
         scratch_shapes=[_vmem((block_q, d), jnp.float32)],
         interpret=interpret,
+        name="flash_attention_dq",
     )(q, k, v, do, lse, delta)
 
     # dK/dV: q minor, two accumulators in VMEM scratch
@@ -374,6 +377,7 @@ def flash_attention_bwd(q: jax.Array, k: jax.Array, v: jax.Array,
             _vmem((block_k, d), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_attention_dkv",
     )(q, k, v, do, lse, delta)
     return dq, dk, dv
 

@@ -150,6 +150,7 @@ def flash_decode_fwd(q: jax.Array, k: jax.Array, v: jax.Array,
             jax.ShapeDtypeStruct((b, kvh, g), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_decode",
     )(lengths.astype(jnp.int32), q, k, v)
 
 
@@ -258,5 +259,6 @@ def flash_decode_paged_fwd(q: jax.Array, k_pool: jax.Array,
             jax.ShapeDtypeStruct((b, kvh, g), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_decode_paged",
     )(page_table.astype(jnp.int32), lengths.astype(jnp.int32),
       q, k_pool, v_pool)

@@ -35,6 +35,7 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
 
 from repro.configs import get_arch, reduced as reduce_cfg
 from repro.configs.base import (
@@ -414,65 +415,80 @@ class Trainer:
 
     # -- one training step ---------------------------------------------------
     def run_step(self) -> Tuple[StepTelemetry, StepPlan, Dict[str, Any]]:
-        tele = dataclasses.replace(self._last, step=self.step,
-                                   tokens_seen=self.tokens_seen)
-        plan = self.stack.plan(tele)
-        # the recovery regulator's data offset skips past a data window the
-        # rollback controller blamed for a divergence
-        offset = (self._recovery_reg.data_offset
-                  if self._recovery_reg is not None else 0)
-        batch = self.pipeline.batch_at(self.step + offset)
-        batch, tokens_step = self.stack.apply(batch, plan)
+        """One step.  Its host phases are profiler spans (``train.step``
+        around ``train.plan``, ``train.batch``, ``train.launch``,
+        ``train.wait`` and ``train.observe``), recorded while a
+        ``jax.profiler`` trace is running and close to free otherwise."""
+        with TraceAnnotation("train.step"):
+            tele = dataclasses.replace(self._last, step=self.step,
+                                       tokens_seen=self.tokens_seen)
+            with TraceAnnotation("train.plan"):
+                plan = self.stack.plan(tele)
+            with TraceAnnotation("train.batch"):
+                # the recovery regulator's data offset skips past a data
+                # window the rollback controller blamed for a divergence
+                offset = (self._recovery_reg.data_offset
+                          if self._recovery_reg is not None else 0)
+                batch = self.pipeline.batch_at(self.step + offset)
+                batch, tokens_step = self.stack.apply(batch, plan)
 
-        shape_key = tuple(sorted((k, v.shape) for k, v in batch.items()))
-        if shape_key not in self._seen_shapes:
-            self._seen_shapes.add(shape_key)
-            self.result.n_compiles += 1
+                shape_key = tuple(sorted((k, v.shape)
+                                         for k, v in batch.items()))
+                if shape_key not in self._seen_shapes:
+                    self._seen_shapes.add(shape_key)
+                    self.result.n_compiles += 1
 
-        # grad_spike fault: a one-step (n_leaves,) multiplier on the raw
-        # per-leaf gradients (None on clean steps keeps the common trace)
-        grad_scale = None
-        if self._pending_grad_fault is not None \
-                and self.fault_injector is not None:
-            factor, substr = self._pending_grad_fault
-            self._pending_grad_fault = None
-            grad_scale = self.fault_injector.grad_scale_vector(
-                self.leaf_labels, self.step, factor, substr)
-        # optional runtime vectors: only passed when active, so the common
-        # trace (no fault, no per-leaf backoff) stays byte-identical
-        extra: Dict[str, Any] = {}
-        if grad_scale is not None:
-            extra["grad_scale"] = grad_scale
-        if self._recovery_reg is not None \
-                and self._recovery_reg.leaf_lr_scales:
-            extra["leaf_lr"] = self._recovery_reg.leaf_lr_vector(
-                self.leaf_labels)
-        self.state, metrics = self.step_fn(
-            self.state, batch, np.float32(plan.lr),
-            np.float32(plan.grad_clip_scale), **extra)
-        # per-leaf vectors (telemetry_level == "per_leaf") ride StepTelemetry,
-        # not the scalar metrics dict the hooks float()
-        metrics, per_leaf = telemetry_lib.split_metrics(metrics)
-        loss = float(metrics["loss"])
-        ratio = (self.tracker.update(loss) if math.isfinite(loss)
-                 else float("inf"))
-        nan = float("nan")
-        post = dataclasses.replace(
-            tele, loss=loss, loss_ratio=ratio,
-            grad_norm=float(metrics["grad_norm"]),
-            grad_norm_clipped=float(metrics.get("grad_norm_clipped", nan)),
-            var_max=float(metrics["var_max"]),
-            var_l1=float(metrics["var_l1"]),
-            gns_small_sq=float(metrics.get("gns_small_sq", nan)),
-            gns_big_sq=float(metrics.get("gns_big_sq", nan)),
-            gns_b_small=float(metrics.get("gns_b_small", nan)),
-            gns_b_big=float(metrics.get("gns_b_big", nan)),
-            per_leaf=per_leaf,
-            leaf_labels=self.leaf_labels if per_leaf is not None else ())
-        self.stack.observe(post, tokens_step)
-        self.step += 1
-        self.tokens_seen += tokens_step
-        self._last = post
+            with TraceAnnotation("train.launch"):
+                # grad_spike fault: a one-step (n_leaves,) multiplier on the
+                # raw per-leaf gradients (None on clean steps keeps the
+                # common trace)
+                grad_scale = None
+                if self._pending_grad_fault is not None \
+                        and self.fault_injector is not None:
+                    factor, substr = self._pending_grad_fault
+                    self._pending_grad_fault = None
+                    grad_scale = self.fault_injector.grad_scale_vector(
+                        self.leaf_labels, self.step, factor, substr)
+                # optional runtime vectors: only passed when active, so the
+                # common trace (no fault, no per-leaf backoff) stays
+                # byte-identical
+                extra: Dict[str, Any] = {}
+                if grad_scale is not None:
+                    extra["grad_scale"] = grad_scale
+                if self._recovery_reg is not None \
+                        and self._recovery_reg.leaf_lr_scales:
+                    extra["leaf_lr"] = self._recovery_reg.leaf_lr_vector(
+                        self.leaf_labels)
+                self.state, metrics = self.step_fn(
+                    self.state, batch, np.float32(plan.lr),
+                    np.float32(plan.grad_clip_scale), **extra)
+            with TraceAnnotation("train.wait"):
+                loss = float(metrics["loss"])
+            with TraceAnnotation("train.observe"):
+                # per-leaf vectors (telemetry_level == "per_leaf") ride
+                # StepTelemetry, not the scalar metrics dict the hooks float()
+                metrics, per_leaf = telemetry_lib.split_metrics(metrics)
+                ratio = (self.tracker.update(loss) if math.isfinite(loss)
+                         else float("inf"))
+                nan = float("nan")
+                post = dataclasses.replace(
+                    tele, loss=loss, loss_ratio=ratio,
+                    grad_norm=float(metrics["grad_norm"]),
+                    grad_norm_clipped=float(metrics.get("grad_norm_clipped",
+                                                        nan)),
+                    var_max=float(metrics["var_max"]),
+                    var_l1=float(metrics["var_l1"]),
+                    gns_small_sq=float(metrics.get("gns_small_sq", nan)),
+                    gns_big_sq=float(metrics.get("gns_big_sq", nan)),
+                    gns_b_small=float(metrics.get("gns_b_small", nan)),
+                    gns_b_big=float(metrics.get("gns_b_big", nan)),
+                    per_leaf=per_leaf,
+                    leaf_labels=(self.leaf_labels if per_leaf is not None
+                                 else ()))
+                self.stack.observe(post, tokens_step)
+                self.step += 1
+                self.tokens_seen += tokens_step
+                self._last = post
         return post, plan, metrics
 
     # -- the loop -------------------------------------------------------------

@@ -11,6 +11,8 @@ The ``xfail(strict=True)`` cases are kernels the compiler still refuses;
 each reason quotes the refusal, and a case that starts to compile fails
 until its mark is removed.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -49,6 +51,18 @@ def _sds(sharding, shape, dtype=jnp.float32):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
+_KERNEL_CALL = re.compile(r"%([\w.-]+)\.\d+ = .* custom-call\(.*"
+                          r'custom_call_target="tpu_custom_call"')
+
+
+def _kernel_names(compiled):
+    """The Pallas kernels' HLO instruction names, without their ``.N``: the
+    names the device trace's operations carry."""
+    return sorted(m.group(1) for m in map(_KERNEL_CALL.search,
+                                          compiled.as_text().splitlines())
+                  if m)
+
+
 # gpt2-117m: 12 heads of 64; SLW buckets below 128 and not multiples of 128
 # run the same kernel as the full 1024
 @pytest.mark.parametrize("direction", ["fwd", "grad"])
@@ -67,13 +81,30 @@ def test_flash_attention_compiles(one_chip, seq, dtype, direction):
     _compile_for_chip(fn, x, x, x)
 
 
+def test_flash_attention_kernels_carry_stable_names(one_chip):
+    # the training step's shape: remat full, and the loss is read, so the
+    # forward runs twice (the first pass and the backward's recompute)
+    attn = jax.checkpoint(
+        lambda q, k, v: flash_attention(q, k, v, causal=True,
+                                        interpret=False))
+    step = jax.value_and_grad(lambda q, k, v: attn(q, k, v).sum(),
+                              argnums=(0, 1, 2))
+    x = _sds(one_chip, (8, 1024, 12, 64))
+    names = _kernel_names(_compile_for_chip(step, x, x, x))
+    assert names == ["flash_attention_delta", "flash_attention_dkv",
+                     "flash_attention_dq", "flash_attention_fwd",
+                     "flash_attention_fwd"]
+    assert all("flash_attention" in n for n in names)
+
+
 def test_flash_decode_compiles(one_chip):
     q = _sds(one_chip, (8, 12, 64))
     cache = _sds(one_chip, (8, 2048, 12, 64))
     lengths = _sds(one_chip, (8,), jnp.int32)
-    _compile_for_chip(
+    compiled = _compile_for_chip(
         lambda q, k, v, n: flash_decode(q, k, v, n, interpret=False),
         q, cache, cache, lengths)
+    assert _kernel_names(compiled) == ["flash_decode"]
 
 
 @pytest.mark.xfail(strict=True, raises=ValueError, reason=(
