@@ -3,6 +3,12 @@
 ``flash_attention`` is a ``jax.custom_vjp`` at the model-facing layout
 (q: (B, S, H, D); k, v: (B, S, KV, D) with H = KV * G):
 
+* tiles: ``kernel.tiles`` chooses ``(block_h, block_q, block_k)`` from
+  the shapes (B*H, S, D and the itemsize): sequence blocks of the whole
+  sequence where its kernels fit VMEM with two heads a step, else 512 (or
+  256, 128), and a group of ``block_h`` heads per grid step, as many as
+  fit.  ``block_q`` / ``block_k`` passed in replace the sequence blocks;
+  the head group is chosen for them.
 * forward: expands KV heads to Q heads (GQA), flattens to (B*H, S, D), pads
   the sequence to a block multiple (padded tail keys masked via
   ``valid_len``), and runs the fused Pallas forward — saving the
@@ -28,7 +34,7 @@ import jax.numpy as jnp
 
 from repro.kernels import resolve_interpret
 from repro.kernels.flash_attention.kernel import (flash_attention_bwd,
-                                                  flash_attention_fwd)
+                                                  flash_attention_fwd, tiles)
 
 
 def _flatten(x: jax.Array, g: int, pad: int) -> jax.Array:
@@ -49,19 +55,18 @@ def _unflatten(x: jax.Array, b: int, s: int) -> jax.Array:
 
 
 def _prep(q, k, v, block_q, block_k):
-    """Shared fwd/bwd prologue: resolve blocks + padding, flatten q/k/v.
+    """Shared fwd/bwd prologue: resolve tiles + padding, flatten q/k/v.
 
-    Returns (g, bq, bk, pad, qf, kf, vf) — the one definition of the layout
-    the residuals are saved in and the backward re-derives.
+    Returns (g, (hg, bq, bk), pad, qf, kf, vf) — the one definition of the
+    layout the residuals are saved in and the backward re-derives.
     """
-    s = q.shape[1]
-    g = q.shape[2] // k.shape[2]
-    bq = min(block_q, s)
-    bk = min(block_k, s)
+    b, s, h, d = q.shape
+    g = h // k.shape[2]
+    blocks = tiles(b * h, s, d, q.dtype.itemsize, block_q, block_k)
     # the padded length must be divisible by *both* blocks, not just the
     # larger one (e.g. s=96, bq=64, bk=96 needs lcm padding, not zero)
-    pad = (-s) % math.lcm(bq, bk)
-    return (g, bq, bk, pad, _flatten(q, 1, pad), _flatten(k, g, pad),
+    pad = (-s) % math.lcm(*blocks[1:])
+    return (g, blocks, pad, _flatten(q, 1, pad), _flatten(k, g, pad),
             _flatten(v, g, pad))
 
 
@@ -73,9 +78,9 @@ def _flash(q, k, v, causal, block_q, block_k, interpret):
 
 def _flash_fwd(q, k, v, causal, block_q, block_k, interpret):
     b, s = q.shape[:2]
-    g, bq, bk, pad, qf, kf, vf = _prep(q, k, v, block_q, block_k)
-    of, lse = flash_attention_fwd(qf, kf, vf, causal=causal, block_q=bq,
-                                  block_k=bk, valid_len=s,
+    _, (hg, bq, bk), _, qf, kf, vf = _prep(q, k, v, block_q, block_k)
+    of, lse = flash_attention_fwd(qf, kf, vf, causal=causal, block_h=hg,
+                                  block_q=bq, block_k=bk, valid_len=s,
                                   interpret=interpret)
     out = _unflatten(of, b, s)
     return out, (q, k, v, out, lse)
@@ -85,12 +90,12 @@ def _flash_bwd(causal, block_q, block_k, interpret, res, do):
     q, k, v, out, lse = res
     b, s, _, d = q.shape
     kv = k.shape[2]
-    g, bq, bk, pad, qf, kf, vf = _prep(q, k, v, block_q, block_k)
+    g, (hg, bq, bk), pad, qf, kf, vf = _prep(q, k, v, block_q, block_k)
     of = _flatten(out, 1, pad)
     dof = _flatten(do, 1, pad)
     dqf, dkf, dvf = flash_attention_bwd(
-        qf, kf, vf, of, lse, dof, causal=causal, block_q=bq, block_k=bk,
-        valid_len=s, interpret=interpret)
+        qf, kf, vf, of, lse, dof, causal=causal, block_h=hg, block_q=bq,
+        block_k=bk, valid_len=s, interpret=interpret)
     dq = _unflatten(dqf, b, s)
     # accumulate per-Q-head dK/dV over each KV head's group of G query
     # heads — in fp32, so bf16 inputs don't compound rounding over G adds
@@ -107,10 +112,13 @@ _flash.defvjp(_flash_fwd, _flash_bwd)
 @functools.partial(jax.jit, static_argnames=("causal", "block_q", "block_k",
                                              "interpret"))
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
-                    causal: bool = True, block_q: int = 128,
-                    block_k: int = 128,
+                    causal: bool = True, block_q: int | None = None,
+                    block_k: int | None = None,
                     interpret: bool | None = None) -> jax.Array:
     """q: (B, S, H, D); k, v: (B, S, KV, D) with H = KV * G. Returns like q.
+
+    ``block_q`` / ``block_k`` of None are chosen from the shapes
+    (``kernel.tiles``).
 
     Differentiable end-to-end: ``jax.grad`` routes through the Pallas
     backward kernels via the custom VJP above.

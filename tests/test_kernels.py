@@ -26,6 +26,8 @@ def _tol(dtype):
     (1, 256, 8, 8, 32),   # MHA
     (2, 192, 6, 2, 16),   # uneven blocks (padding path)
     (1, 64, 4, 1, 128),   # MQA
+    (2, 136, 4, 2, 64),   # an SLW bucket that pads, GQA, 8 heads a step
+    (2, 8, 6, 3, 16),     # the first SLW bucket: one block, 12 heads a step
 ])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 @pytest.mark.parametrize("causal", [True, False])
@@ -53,6 +55,8 @@ def test_flash_attention_sweep(b, s, h, kv, d, dtype, causal):
     (1, 160, 4, 1, 16, True),    # padded tail (160 % 64 != 0) + MQA
     (2, 96, 6, 2, 16, False),    # non-causal + padding + GQA
     (1, 128, 4, 4, 32, True),    # MHA
+    (2, 136, 4, 2, 64, True),    # SLW bucket 136: pads, GQA, 8 heads a step
+    (2, 8, 6, 3, 16, True),      # SLW bucket 8: one block, 12 heads a step
 ])
 def test_flash_attention_grads_match_reference(b, s, h, kv, d, causal):
     """dq/dk/dv of the custom_vjp path vs jax.grad of the dense oracle."""
@@ -97,6 +101,123 @@ def test_flash_attention_grads_mixed_blocks():
     for a, b_ in zip(g, gr):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b_), atol=1e-4,
                                    rtol=1e-4)
+
+
+@pytest.mark.parametrize("s", [8, 136])
+def test_flash_attention_chosen_tiles_match_blockwise(s):
+    """With no blocks given the chooser folds every head into one grid
+    step (block_h = B*H here); forward and gradients match the model's
+    blockwise attention, causal with GQA (G = 2)."""
+    from repro.kernels.flash_attention.kernel import tiles
+    from repro.models.attention import blockwise_attention
+    b, h, kv, d = 2, 4, 2, 64
+    assert tiles(b * h, s, d, 4)[0] == b * h
+    ks = jax.random.split(jax.random.PRNGKey(s), 4)
+    q = jax.random.normal(ks[0], (b, s, h, d))
+    k = jax.random.normal(ks[1], (b, s, kv, d))
+    v = jax.random.normal(ks[2], (b, s, kv, d))
+    w = jax.random.normal(ks[3], (b, s, h, d))
+    fa = lambda q, k, v: flash_attention(q, k, v, causal=True,
+                                         interpret=True)
+    ref = lambda q, k, v: blockwise_attention(q, k, v, causal=True,
+                                              block_kv=32)
+    np.testing.assert_allclose(np.asarray(fa(q, k, v)),
+                               np.asarray(ref(q, k, v)), atol=2e-5,
+                               rtol=2e-5)
+    loss = lambda fn: (lambda q, k, v: jnp.sum(fn(q, k, v) * w))
+    g = jax.grad(loss(fa), (0, 1, 2))(q, k, v)
+    gr = jax.grad(loss(ref), (0, 1, 2))(q, k, v)
+    for name, a, b_ in zip(("dq", "dk", "dv"), g, gr):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b_), atol=1e-4,
+                                   rtol=1e-4, err_msg=name)
+
+
+# the SLW cell (32 rows x 12 heads of 64, float32): bucket -> (block_h,
+# block, padded length, grid steps per call of the forward, dQ and dK/dV;
+# the delta kernel takes one step per q block).  One head and 128 x 128
+# tiles took 384 * (S_pad / 128)^2: 384 at seq 8, 1,536 at 136, 24,576 at
+# 1024.
+SLW_TILES = {
+    8: (64, 8, 8, 6),
+    136: (12, 136, 136, 32),
+    264: (6, 264, 264, 64),
+    392: (4, 392, 392, 96),
+    520: (2, 520, 520, 192),
+    648: (2, 648, 648, 192),
+    776: (3, 512, 1024, 512),
+    904: (3, 512, 1024, 512),
+    1024: (3, 512, 1024, 512),
+}
+
+
+def _grid(bh, s, tile):
+    import math
+    block_h, bq, bk = tile
+    s_pad = s + (-s) % math.lcm(bq, bk)
+    return s_pad, (bh // block_h) * (s_pad // bq) * (s_pad // bk)
+
+
+@pytest.mark.parametrize("s", sorted(SLW_TILES))
+def test_flash_tiles_for_the_slw_buckets(s):
+    from repro.kernels.flash_attention.kernel import (VMEM_BUDGET, tiles,
+                                                      vmem_bytes)
+    block_h, block, s_pad, steps = SLW_TILES[s]
+    tile = tiles(384, s, 64, 4)
+    assert tile == (block_h, block, block)
+    assert _grid(384, s, tile) == (s_pad, steps)
+    assert vmem_bytes(*tile, 64, 4) <= VMEM_BUDGET
+
+
+@pytest.mark.parametrize("bh,s,itemsize,want", [
+    (12, 8, 4, (12, 8, 8)),          # serving prefill at batch 1
+    (12, 136, 4, (12, 136, 136)),
+    (12, 1024, 4, (3, 512, 512)),
+    (21, 136, 4, (7, 136, 136)),     # 3 x 7 heads: no divisor 8 or 16
+    (21, 520, 4, (1, 520, 520)),     # fits two heads, but 21 is odd
+    (21, 1024, 4, (3, 512, 512)),
+    (384, 136, 2, (16, 136, 136)),   # bfloat16 blocks are half the bytes
+    (384, 776, 2, (2, 776, 776)),
+    (384, 1024, 2, (4, 512, 512)),
+    (1, 1024, 4, (1, 1024, 1024)),   # one head: the whole sequence
+])
+def test_flash_tiles_adapt_to_the_shapes(bh, s, itemsize, want):
+    from repro.kernels.flash_attention.kernel import (VMEM_BUDGET, tiles,
+                                                      vmem_bytes)
+    assert tiles(bh, s, 64, itemsize) == want
+    if want[0] > 1:
+        assert vmem_bytes(*want, 64, itemsize) <= VMEM_BUDGET
+
+
+def test_flash_tiles_keep_given_blocks():
+    """Blocks passed in win (clamped to the sequence); a block given alone
+    pairs with 128; the head group is still chosen for them."""
+    from repro.kernels.flash_attention.kernel import (VMEM_BUDGET, tiles,
+                                                      vmem_bytes)
+    assert tiles(384, 1024, 64, 4, 64, 64) == (32, 64, 64)
+    assert tiles(384, 1024, 64, 4, 256) == (12, 256, 128)
+    assert tiles(8, 96, 16, 4, 64, 128) == (8, 64, 96)
+    assert vmem_bytes(32, 64, 64, 64, 4) <= VMEM_BUDGET
+    assert vmem_bytes(48, 64, 64, 64, 4) > VMEM_BUDGET
+
+
+def test_flash_attention_head_groups_match_one_head_per_step():
+    """Folding heads into a grid step changes the tiling only: per head
+    each tile does the same arithmetic, so block_h = 4 matches block_h = 1
+    (the grid of one head per step) to float32 rounding, forward and
+    backward, with a padded tail."""
+    from repro.kernels.flash_attention import kernel as K
+    bh, s, d = 8, 192, 32
+    ks = jax.random.split(jax.random.PRNGKey(11), 4)
+    q, k, v, do = (jax.random.normal(k_, (bh, s, d)) for k_ in ks)
+    kw = dict(block_q=64, block_k=64, valid_len=160, interpret=True)
+    runs = []
+    for h in (1, 4):
+        o, lse = K.flash_attention_fwd(q, k, v, block_h=h, **kw)
+        runs.append((o, lse) + K.flash_attention_bwd(q, k, v, o, lse, do,
+                                                     block_h=h, **kw))
+    for name, a, b_ in zip(("o", "lse", "dq", "dk", "dv"), *runs):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b_), atol=1e-6,
+                                   rtol=1e-6, err_msg=name)
 
 
 def test_flash_attention_lcm_padding():

@@ -63,22 +63,35 @@ def _kernel_names(compiled):
                   if m)
 
 
+def _flash_fwd(q, k, v):
+    return flash_attention(q, k, v, causal=True, interpret=False)
+
+
 # gpt2-117m: 12 heads of 64; SLW buckets below 128 and not multiples of 128
-# run the same kernel as the full 1024
+# run the same kernels as the full 1024, each with the head group and blocks
+# its shape is given: whole-sequence blocks up to 776 in bfloat16 (648 in
+# float32), 512 x 512 padded above (904)
 @pytest.mark.parametrize("direction", ["fwd", "grad"])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
                          ids=["f32", "bf16"])
-@pytest.mark.parametrize("seq", [1024, 72])
+@pytest.mark.parametrize("seq", [1024, 72, 136, 904, 264, 520, 776])
 def test_flash_attention_compiles(one_chip, seq, dtype, direction):
-    def fwd(q, k, v):
-        return flash_attention(q, k, v, causal=True, interpret=False)
-
     def loss(q, k, v):
-        return fwd(q, k, v).astype(jnp.float32).sum()
+        return _flash_fwd(q, k, v).astype(jnp.float32).sum()
 
-    fn = fwd if direction == "fwd" else jax.grad(loss, argnums=(0, 1, 2))
+    fn = _flash_fwd if direction == "fwd" else jax.grad(loss,
+                                                        argnums=(0, 1, 2))
     x = _sds(one_chip, (8, seq, 12, 64), dtype)
     _compile_for_chip(fn, x, x, x)
+
+
+# serving prefill at batch 1: twelve heads in all, so one head group
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("seq", [136, 1024])
+def test_flash_attention_prefill_compiles(one_chip, seq, dtype):
+    x = _sds(one_chip, (1, seq, 12, 64), dtype)
+    _compile_for_chip(_flash_fwd, x, x, x)
 
 
 def test_flash_attention_kernels_carry_stable_names(one_chip):
