@@ -5,8 +5,15 @@ The rest is looked up by those names, so a later cell, mix, configuration
 or metric is new files plus new entries, and no edit:
 
 * ``<configuration file>`` named in ``BENCHMARK.json``: the model's sizes
-  (GPT-2 ``config.json`` keys), ``reduced``/``assumed``, and how the
-  program runs it (``program``);
+  (the keys of its published ``config.json``, ``model_type`` among them),
+  ``reduced``/``assumed``, and how the program runs it (``program``);
+* the architecture's files, found by the configuration's ``model_type``
+  (``ARCH_FILES``): ``bench/reference/<model_type>.py``, the plain
+  reference, its ``Dims`` (``dims_from_config``) and its parameter tree
+  (``weight_shapes``, ``weight_init``); ``bench/programs/<model_type>.py``,
+  the program's model config for the file (``model_config``);
+  ``bench/work/<model_type>_step.py``, the work of a step from its shapes
+  (``train_step_flops``, ``attention_calls``);
 * ``bench/traffic/<traffic>.json``: the training job;
 * ``bench/limits/<cell>.json``: the limits of the numbers ``correct``
   compares, with the readings each was set from;
@@ -15,19 +22,47 @@ or metric is new files plus new entries, and no edit:
 """
 from __future__ import annotations
 
+import functools
+import hashlib
 import importlib.util
 import json
+import re
+import sys
 from dataclasses import dataclass, field
 from pathlib import Path
+from types import ModuleType
 from typing import Any, Callable, Dict, List, Optional
 
 import jax.numpy as jnp
 
-from bench.reference.gpt2 import Dims, dims_from_config
+ARCH_FILES = {
+    "reference": "bench/reference/{}.py",
+    "programs": "bench/programs/{}.py",
+    "work": "bench/work/{}_step.py",
+}
 
 
 class CellError(Exception):
     """The files do not describe a runnable cell."""
+
+
+@functools.lru_cache(maxsize=None)
+def _arch_module(path: Path) -> ModuleType:
+    # once per file, so its classes and jitted functions stay the same
+    # objects; registered under a name of its own, as dataclasses want
+    name = "bench_arch_" + hashlib.sha256(str(path).encode()).hexdigest()[:16]
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    sys.modules[name] = mod
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def arch_path(root: Path, part: str, model_type: str) -> Path:
+    """Where ``part`` of the architecture ``model_type`` lives."""
+    if not re.fullmatch(r"[A-Za-z0-9_][A-Za-z0-9_.-]*", str(model_type)):
+        raise CellError(f"model_type {model_type!r} is not a plain name")
+    return Path(root) / ARCH_FILES[part].format(model_type)
 
 
 @dataclass
@@ -48,9 +83,15 @@ class Cell:
     def kind(self) -> str:
         return self.traffic["kind"]
 
+    def arch(self, part: str) -> ModuleType:
+        """The module of ``part`` (a key of ``ARCH_FILES``) of the
+        configuration's architecture."""
+        return _arch_module(
+            arch_path(self.root, part, self.config["model_type"]).resolve())
+
     @property
-    def dims(self) -> Dims:
-        return dims_from_config(self.config)
+    def dims(self):
+        return self.arch("reference").dims_from_config(self.config)
 
     @property
     def program(self) -> Dict[str, Any]:
@@ -89,6 +130,13 @@ def load_cell(root: Path, name: str, overrides: Optional[dict] = None
         raise CellError(f"workload {name!r} names unknown config "
                         f"{w['config']!r}")
     config = _load_json(root / configs[w["config"]]["file"])
+    if "model_type" not in config:
+        raise CellError(f"config {w['config']!r} has no model_type")
+    for part in ARCH_FILES:
+        path = arch_path(root, part, config["model_type"])
+        if not path.exists():
+            raise CellError(f"model_type {config['model_type']!r} has no "
+                            f"{part} file: looked for {path}")
     traffic = _load_json(root / "bench" / "traffic" / f"{w['traffic']}.json")
     limits = _load_json(root / "bench" / "limits" / f"{name}.json")
     e2e = [m for m in bench["end_to_end"]

@@ -11,16 +11,10 @@ from bench.harness.cell import Cell
 
 
 def model_config(cell: Cell):
-    """The program's ``ModelConfig`` for a GPT-2 configuration file."""
-    from repro.configs.base import ModelConfig
-    d, p = cell.dims, cell.program
-    return ModelConfig(
-        name=cell.config_name, family="dense", n_layers=d.n_layers,
-        d_model=d.d_model, n_heads=d.n_heads, n_kv_heads=d.n_heads,
-        d_ff=d.d_ff, vocab_size=d.vocab, pos_emb="learned",
-        norm="layernorm", mlp="gelu", norm_eps=d.eps, tie_embeddings=True,
-        max_seq_len=d.n_positions, attn_backend=p["attn_backend"],
-        decode_backend=p["decode_backend"])
+    """The program's ``ModelConfig`` for the cell's configuration file,
+    as ``bench/programs/<model_type>.py`` maps it."""
+    return cell.arch("programs").model_config(cell.config_name, cell.dims,
+                                              cell.program)
 
 
 def free(*trees) -> None:

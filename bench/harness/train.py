@@ -40,7 +40,7 @@ from bench.harness import compare, program, weights
 from bench.harness import trace as trace_mod
 from bench.harness.cell import Cell
 from bench.harness.spans import Spans
-from bench.reference import gpt2 as ref_mod
+from bench.reference import common
 
 N_CHECK_STEPS = 3
 
@@ -77,7 +77,7 @@ def build_trainer(cell: Cell, seed: int):
     tc = dataclasses.replace(tc, model=program.model_config(cell))
     trainer = train_cli.Trainer(tc, dp_size=1)
     program.free(trainer.state)
-    params = weights.make(seed, cell.dims)
+    params = weights.make(seed, cell)
     trainer.state = {"params": params,
                      "opt": build_optimizer(tc.optimizer).init(params),
                      "step": jnp.zeros((), jnp.int32)}
@@ -133,10 +133,10 @@ def drive(trainer, n_steps: int, keep_delta: bool) -> Dict[str, Any]:
                       "clip_scale": plan.grad_clip_scale, "batch": batch})
         if k == 0:
             m = trainer.state["opt"]["adam"]["m"]
-            grad = [n / (1.0 - b1) for n in ref_mod.leaf_norms(m)]
+            grad = [n / (1.0 - b1) for n in common.leaf_norms(m)]
     delta = None
     if keep_delta:
-        delta = ref_mod.diff_norms(trainer.state["params"], p0)
+        delta = common.diff_norms(trainer.state["params"], p0)
         program.free(p0)
     return {"steps": steps, "grad": grad, "delta": delta}
 
@@ -169,29 +169,29 @@ def reference_runs(cell: Cell, seed: int, runs: List[Dict[str, Any]],
     """The plain reference over each checked run, from the same seeded
     weights and rows, in ``prec``."""
     dims = cell.dims
-    ref = ref_mod.Reference(dims, prec,
-                            rows_per_block=cell.traffic["ref_rows_per_block"])
+    ref = cell.arch("reference").Reference(
+        dims, prec, rows_per_block=cell.traffic["ref_rows_per_block"])
     feed = Feed(seed, cell.traffic["rows"], cell.traffic["seq"], dims.vocab)
-    p_init = weights.make(seed, dims)
+    p_init = weights.make(seed, cell)
     out = []
     for run in runs:
-        params, opt = p_init, ref_mod.adamw_init(p_init)
+        params, opt = p_init, common.adamw_init(p_init)
         losses, grad = [], None
         for k, st in enumerate(run["steps"]):
             b = feed.batch(st["batch"])
             tok = b["tokens"][:st["rows"], :st["seq"]]
             lab = b["labels"][:st["rows"], :st["seq"]]
             loss, g = ref.loss_and_grad(params, tok, lab)
-            params, clipped, opt = ref_mod.adamw_step(
+            params, clipped, opt = common.adamw_step(
                 params, g, opt, lr=st["lr"],
                 clip=opt_cfg.grad_clip * st["clip_scale"], b1=opt_cfg.beta1,
                 b2=opt_cfg.beta2, eps=opt_cfg.eps,
                 weight_decay=opt_cfg.weight_decay)
             losses.append(loss)
             if k == 0:
-                grad = ref_mod.leaf_norms(clipped)
+                grad = common.leaf_norms(clipped)
             program.free(g, clipped)
-        delta = (ref_mod.diff_norms(params, p_init)
+        delta = (common.diff_norms(params, p_init)
                  if run["delta"] is not None else None)
         program.free(params, opt["m"], opt["v"])
         out.append({"losses": losses, "grad": grad, "delta": delta})

@@ -1,9 +1,10 @@
 """Share of its roofline that the flash-attention backward kernels reach in
 training.
 
-The least time of the backward of every layer of every step in the window
+The least time of the backward of every call of every step in the window
 (``bench/work/flash_attention.py``: forward and backward, less the
-forward), over the device seconds of the custom calls named
+forward; the calls of a step are the architecture's ``attention_calls``),
+over the device seconds of the custom calls named
 ``flash_attention_delta``, ``flash_attention_dq`` and
 ``flash_attention_dkv``."""
 from bench.work import flash_attention as work
@@ -18,10 +19,9 @@ def read(ctx):
     spent = ctx.trace.kernel_s(KERNELS)
     if spent <= 0:
         return None
-    d, item = ctx.dims, ctx.cell.dtype.itemsize
-    least = sum(d.n_layers * (
-        work.least_seconds(rows, d.n_heads, seq, d.head_dim, item, ctx.peaks)
-        - work.least_seconds(rows, d.n_heads, seq, d.head_dim, item,
-                             ctx.peaks, backward=False))
-        for rows, seq in ctx.steps)
+    calls = ctx.cell.arch("work").attention_calls
+    item = ctx.cell.dtype.itemsize
+    least = sum(work.step_least_seconds(calls(ctx.dims, rows, seq), item,
+                                        ctx.peaks, "bwd")
+                for rows, seq in ctx.steps)
     return 100.0 * least / spent

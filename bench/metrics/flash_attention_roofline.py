@@ -2,8 +2,9 @@
 
 The least time the chip could take for the attention work the window's
 steps need (``bench/work/flash_attention.py``: forward and backward of
-every layer of every step, from its shapes), over the summed device time
-of the forward, delta, dQ and dK/dV kernels in the trace."""
+every call of every step, from its shapes; the calls of a step are the
+architecture's ``attention_calls``), over the summed device time of the
+forward, delta, dQ and dK/dV kernels in the trace."""
 from bench.work import flash_attention as work
 
 
@@ -13,8 +14,9 @@ def read(ctx):
     spent = ctx.trace.kernel_s(work.KERNELS)
     if spent <= 0:
         return None
-    d, item = ctx.dims, ctx.cell.dtype.itemsize
-    least = sum(d.n_layers * work.least_seconds(rows, d.n_heads, seq,
-                                                d.head_dim, item, ctx.peaks)
+    calls = ctx.cell.arch("work").attention_calls
+    item = ctx.cell.dtype.itemsize
+    least = sum(work.step_least_seconds(calls(ctx.dims, rows, seq), item,
+                                        ctx.peaks, "both")
                 for rows, seq in ctx.steps)
     return 100.0 * least / spent

@@ -1,4 +1,4 @@
-"""Plain GPT-2: forward pass, loss, gradients and one AdamW step.
+"""Plain GPT-2: its parameter tree, forward pass, loss and gradients.
 
 Written from the GPT-2 description (Radford et al. 2019) in straightforward
 ``jax.numpy``: learned positions, pre-LayerNorm blocks, causal softmax
@@ -6,7 +6,9 @@ attention over the whole sequence, a tanh-GELU MLP, a final LayerNorm and
 an output head tied to the token embedding.  It imports nothing of the
 program under test: no kernels, no cache, no batching tricks.
 
-The parameter tree is the benchmark's own (``bench/harness/weights.py``):
+The parameter tree is the one the program's dense model reads, and this
+file defines it (``weight_shapes``, ``weight_init``), so the benchmark's
+seeded weights (``bench/harness/weights.py``) are made to its layout:
 
     embed (V_pad, d), pos_embed (P, d), final_norm {scale, bias},
     layers: ln1/ln2 {scale, bias} (L, d); attn wq/wk/wv (L, d, H, Dh),
@@ -16,15 +18,9 @@ The parameter tree is the benchmark's own (``bench/harness/weights.py``):
 Rows of ``embed`` past the real vocabulary are padding: the logits, the
 softmax and the loss run over the first ``vocab`` rows only.
 
-``prec`` is the precision the arithmetic runs in.  ``"float32"`` runs
-every matrix product at ``"highest"`` precision: the reference.  The two
-lower ones are controls, the steps a later change would be tempted to
-take: ``"bfloat16"`` stores parameters and activations in bfloat16 (the
-normalisation, softmax and loss statistics stay float32); ``"int8"`` keeps
-float32 storage and rounds both operands of every matrix product to 255
-levels of a symmetric per-tensor scale (max |x| / 127), as an int8 matmul
-would, below the one bfloat16 pass that XLA's default precision makes of a
-float32 product on a TPU.  Departures from the published model: no dropout
+``prec`` is the precision the arithmetic runs in (``bench/reference/
+common.py``): ``"float32"`` is the reference, ``"bfloat16"`` and
+``"int8"`` the controls.  Departures from the published model: no dropout
 (the program has none).
 
 Memory: gradients are accumulated over blocks of ``rows_per_block`` rows,
@@ -35,10 +31,12 @@ from __future__ import annotations
 
 import math
 from dataclasses import dataclass
-from typing import Any, Dict, List
+from typing import Any, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
+
+from bench.reference.common import cast, matmul_precision, mm, storage
 
 
 @dataclass(frozen=True)
@@ -69,25 +67,33 @@ def dims_from_config(cfg: Dict[str, Any]) -> Dims:
                 eps=cfg["layer_norm_epsilon"])
 
 
-PRECISIONS = ("float32", "bfloat16", "int8")
+def weight_shapes(d: Dims) -> Dict[str, Any]:
+    """The parameter tree, as shapes."""
+    L, D, H, K, F = d.n_layers, d.d_model, d.n_heads, d.head_dim, d.d_ff
+    norm = {"scale": (L, D), "bias": (L, D)}
+    return {
+        "embed": (d.vocab_padded, D),
+        "pos_embed": (d.n_positions, D),
+        "final_norm": {"scale": (D,), "bias": (D,)},
+        "layers": {
+            "ln1": dict(norm), "ln2": dict(norm),
+            "attn": {"wq": (L, D, H, K), "wk": (L, D, H, K),
+                     "wv": (L, D, H, K), "wo": (L, H, K, D)},
+            "mlp": {"w_up": (L, D, F), "b_up": (L, F),
+                    "w_down": (L, F, D), "b_down": (L, D)},
+        },
+    }
 
 
-def _storage(prec: str):
-    if prec not in PRECISIONS:
-        raise ValueError(f"unknown precision {prec!r} (want {PRECISIONS})")
-    return jnp.bfloat16 if prec == "bfloat16" else jnp.float32
-
-
-def _q8(x):
-    """Round to the int8 grid of a symmetric per-tensor scale."""
-    s = jnp.maximum(jnp.max(jnp.abs(x)), 1e-30) / 127.0
-    return jnp.round(x / s) * s
-
-
-def _mm(spec: str, a, b, prec: str):
-    if prec == "int8":
-        a, b = _q8(a), _q8(b)
-    return jnp.einsum(spec, a, b)
+def weight_init(path: str, d: Dims) -> Tuple[float, float]:
+    """(mean, std) of the normal draw of the leaf at ``path``: GPT-2's
+    std 0.02, the two residual projections scaled by 1/sqrt(2L).  Biases
+    and LayerNorm parameters are drawn too, not left at 0 and 1, so a
+    program that dropped one of them would not match the reference."""
+    mean = 1.0 if path.endswith("scale") else 0.0
+    if path.endswith(("wo", "w_down")):
+        return mean, 0.02 / math.sqrt(2 * d.n_layers)
+    return mean, 0.02
 
 
 def _layer_norm(x, p, eps):
@@ -109,31 +115,26 @@ def _block(x, lp, dims: Dims, prec: str):
     s = x.shape[1]
     h = _layer_norm(x, lp["ln1"], dims.eps)
     a = lp["attn"]
-    q = _mm("bsd,dhk->bshk", h, a["wq"], prec)
-    k = _mm("bsd,dhk->bshk", h, a["wk"], prec)
-    v = _mm("bsd,dhk->bshk", h, a["wv"], prec)
-    scores = _mm("bqhk,bjhk->bhqj", q, k, prec).astype(jnp.float32)
+    q = mm("bsd,dhk->bshk", h, a["wq"], prec)
+    k = mm("bsd,dhk->bshk", h, a["wk"], prec)
+    v = mm("bsd,dhk->bshk", h, a["wv"], prec)
+    scores = mm("bqhk,bjhk->bhqj", q, k, prec).astype(jnp.float32)
     scores = scores / math.sqrt(dims.head_dim)
     causal = jnp.tril(jnp.ones((s, s), bool))
     scores = jnp.where(causal, scores, -jnp.inf)
     probs = jax.nn.softmax(scores, axis=-1).astype(dt)
-    ctx = _mm("bhqj,bjhk->bqhk", probs, v, prec)
-    x = x + _mm("bshk,hkd->bsd", ctx, a["wo"], prec)
+    ctx = mm("bhqj,bjhk->bqhk", probs, v, prec)
+    x = x + mm("bshk,hkd->bsd", ctx, a["wo"], prec)
     h = _layer_norm(x, lp["ln2"], dims.eps)
     m = lp["mlp"]
-    up = _gelu(_mm("bsd,df->bsf", h, m["w_up"], prec) + m["b_up"])
-    return x + _mm("bsf,fd->bsd", up, m["w_down"], prec) + m["b_down"]
-
-
-def _cast(tree, dtype):
-    return jax.tree_util.tree_map(lambda t: t.astype(dtype), tree)
+    up = _gelu(mm("bsd,df->bsf", h, m["w_up"], prec) + m["b_up"])
+    return x + mm("bsf,fd->bsd", up, m["w_down"], prec) + m["b_down"]
 
 
 def logits(params, tokens, dims: Dims, prec: str = "float32"):
     """(B, S) token ids -> (B, S, vocab) float32 logits."""
-    with jax.default_matmul_precision(
-            "highest" if prec != "bfloat16" else "default"):
-        p = _cast(params, _storage(prec))
+    with jax.default_matmul_precision(matmul_precision(prec)):
+        p = cast(params, storage(prec))
         s = tokens.shape[1]
         x = jnp.take(p["embed"], tokens, axis=0) + p["pos_embed"][:s]
 
@@ -143,7 +144,7 @@ def logits(params, tokens, dims: Dims, prec: str = "float32"):
 
         x, _ = jax.lax.scan(body, x, p["layers"])
         x = _layer_norm(x, p["final_norm"], dims.eps)
-        out = _mm("bsd,vd->bsv", x, p["embed"][:dims.vocab], prec)
+        out = mm("bsd,vd->bsv", x, p["embed"][:dims.vocab], prec)
         return out.astype(jnp.float32)
 
 
@@ -156,11 +157,11 @@ def loss_sum(params, tokens, labels, dims: Dims, prec: str = "float32"):
 
 
 class Reference:
-    """Loss, gradients and AdamW steps of the plain model, in row blocks."""
+    """Loss and gradients of the plain model, in row blocks."""
 
     def __init__(self, dims: Dims, prec: str = "float32",
                  rows_per_block: int = 1):
-        _storage(prec)
+        storage(prec)
         self.dims = dims
         self.prec = prec
         self.rows_per_block = rows_per_block
@@ -185,52 +186,3 @@ class Reference:
         n = tokens.size
         grads = jax.tree_util.tree_map(lambda g: g / n, grads)
         return total / n, grads
-
-
-def adamw_init(params):
-    zeros = jax.tree_util.tree_map(
-        lambda p: jnp.zeros(p.shape, jnp.float32), params)
-    return {"m": zeros, "v": jax.tree_util.tree_map(jnp.copy, zeros),
-            "count": 0}
-
-
-@jax.jit
-def _adamw(params, grads, m, v, count, lr, clip, b1, b2, eps, wd):
-    gnorm = jnp.sqrt(sum(jnp.sum(jnp.square(g))
-                         for g in jax.tree_util.tree_leaves(grads)))
-    scale = jnp.minimum(1.0, clip / jnp.maximum(gnorm, 1e-12))
-    grads = jax.tree_util.tree_map(lambda g: g * scale, grads)
-    m = jax.tree_util.tree_map(lambda m_, g: b1 * m_ + (1 - b1) * g, m, grads)
-    v = jax.tree_util.tree_map(lambda v_, g: b2 * v_ + (1 - b2) * g * g,
-                               v, grads)
-    bc1 = 1 - b1 ** count
-    bc2 = 1 - b2 ** count
-    new = jax.tree_util.tree_map(
-        lambda p, m_, v_: p - lr * ((m_ / bc1) / (jnp.sqrt(v_ / bc2) + eps)
-                                    + wd * p), params, m, v)
-    return new, grads, m, v
-
-
-def adamw_step(params, grads, opt, *, lr, clip, b1, b2, eps, weight_decay):
-    """Global-norm clip, Adam with bias correction, decoupled weight decay
-    on every leaf, then the learning rate.  Returns (params, clipped
-    gradients, opt)."""
-    count = opt["count"] + 1
-    new, clipped, m, v = _adamw(params, grads, opt["m"], opt["v"],
-                                jnp.float32(count), jnp.float32(lr),
-                                jnp.float32(clip), b1, b2, eps, weight_decay)
-    return new, clipped, {"m": m, "v": v, "count": count}
-
-
-def leaf_norms(tree) -> List[float]:
-    return [float(x) for x in jax.jit(lambda t: [
-        jnp.sqrt(jnp.sum(jnp.square(x.astype(jnp.float32))))
-        for x in jax.tree_util.tree_leaves(t)])(tree)]
-
-
-def diff_norms(a, b) -> List[float]:
-    return [float(x) for x in jax.jit(lambda s, t: [
-        jnp.sqrt(jnp.sum(jnp.square(x.astype(jnp.float32)
-                                    - y.astype(jnp.float32))))
-        for x, y in zip(jax.tree_util.tree_leaves(s),
-                        jax.tree_util.tree_leaves(t))])(a, b)]

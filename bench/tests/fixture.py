@@ -1,9 +1,9 @@
 """A tiny benchmark tree, made from files alone, for tests on the CPU.
 
 It holds its own ``BENCHMARK.json``, configuration, traffic and limits
-files under a temporary root, and shares the repository's metric readers
-and peaks; the harness finds all of it by name, as it finds the real
-cells."""
+files under a temporary root, and copies of the repository's
+architecture files, metric readers and peaks; the harness finds all of it
+by name, as it finds the real cells."""
 from __future__ import annotations
 
 import json
@@ -52,7 +52,9 @@ def make_root(tmp: Path) -> Path:
     (root / "bench" / "configs").mkdir(parents=True)
     (root / "bench" / "traffic").mkdir()
     (root / "bench" / "limits").mkdir()
-    shutil.copytree(REPO / "bench" / "metrics", root / "bench" / "metrics")
+    for part in ("metrics", "reference", "programs", "work"):
+        shutil.copytree(REPO / "bench" / part, root / "bench" / part,
+                        ignore=shutil.ignore_patterns("__pycache__"))
     shutil.copy(REPO / "bench" / "peaks.json", root / "bench" / "peaks.json")
     (root / "bench" / "configs" / "tiny.json").write_text(json.dumps(TINY))
     for name, mix in TRAFFIC.items():

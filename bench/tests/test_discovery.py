@@ -4,7 +4,8 @@ import json
 
 import pytest
 
-from bench.harness.cell import CellError, load_cell, metric_reader, peaks_for
+from bench.harness.cell import (ARCH_FILES, CellError, load_cell,
+                                metric_reader, peaks_for)
 from bench.tests.fixture import REPO, make_root
 
 
@@ -15,9 +16,12 @@ def test_fixture_cell_is_found_from_files(tmp_path):
     assert cell.dims.n_layers == 2 and cell.dims.d_ff == 512
     assert [m["name"] for m in cell.end_to_end] == ["train_tokens_per_s",
                                                      "setup_s"]
-    names = {m["name"] for m in cell.per_layer}
-    assert names == {"device_idle.train", "train_mfu",
-                     "flash_attention_roofline"}
+    # the fixture gives its cell every metric that the repository's
+    # training cells report
+    bench = json.loads((REPO / "BENCHMARK.json").read_text())
+    want = {m["name"] for m in bench["per_layer"]
+            if "gpt2-117m.train-slw" in m.get("workloads", [])}
+    assert {m["name"] for m in cell.per_layer} == want
     for m in cell.per_layer:
         assert callable(metric_reader(root, m["name"]))
 
@@ -69,6 +73,9 @@ def test_every_real_cell_has_its_files():
         cell = load_cell(REPO, w["name"])
         for m in cell.per_layer:
             metric_reader(REPO, m["name"])
+        assert cell.dims.n_layers == cell.config["n_layer"]
+        for part in ARCH_FILES:
+            assert cell.arch(part).__file__.startswith(str(REPO))
 
 
 def test_a_number_with_a_null_limit_is_not_compared():

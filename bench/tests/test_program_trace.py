@@ -16,6 +16,7 @@ from bench.harness.cell import metric_reader
 from bench.reference.gpt2 import dims_from_config
 from bench.tests.fixture import REPO
 from bench.work import flash_attention as work
+from bench.work import gpt2_step
 
 DATA = Path(__file__).parent / "data"
 PROBE = DATA / "probe_train.xplane.pb"
@@ -41,7 +42,8 @@ def _ctx(trace):
     return SimpleNamespace(
         kind="train", trace=trace, peaks=peaks["TPU v5 lite"],
         dims=dims_from_config({**cfg, "n_layer": LAYERS}),
-        cell=SimpleNamespace(dtype=jnp.dtype(jnp.float32)),
+        cell=SimpleNamespace(dtype=jnp.dtype(jnp.float32),
+                             arch={"work": gpt2_step}.__getitem__),
         steps=[(ROWS, SEQ)] * 2)
 
 
@@ -99,9 +101,10 @@ def test_roofline_readers_split_the_attention_seconds(probe):
     ctx = _ctx(probe)
     d, peaks = ctx.dims, ctx.peaks
     whole = LAYERS * 2 * work.least_seconds(ROWS, d.n_heads, SEQ,
-                                            d.head_dim, 4, peaks)
+                                            d.head_dim, d.head_dim, 4, peaks)
     fwd = LAYERS * 2 * work.least_seconds(ROWS, d.n_heads, SEQ, d.head_dim,
-                                          4, peaks, backward=False)
+                                          d.head_dim, 4, peaks,
+                                          backward=False)
     spent_fwd = 100.0 * fwd / _read("flash_attention_fwd_roofline", probe)
     spent_bwd = 100.0 * (whole - fwd) / _read("flash_attention_bwd_roofline",
                                               probe)

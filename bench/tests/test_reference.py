@@ -7,7 +7,7 @@ import pytest
 
 from bench.harness import program, weights
 from bench.harness.cell import load_cell
-from bench.reference import gpt2 as ref
+from bench.reference import common
 from bench.tests.fixture import make_root
 
 
@@ -26,7 +26,7 @@ def _program_model(cell):
 def test_weights_fit_the_program_tree(cell):
     from repro.models import model_zoo
     got = jax.tree_util.tree_map(lambda x: x.shape,
-                                 weights.make(3, cell.dims))
+                                 weights.make(3, cell))
     want = jax.tree_util.tree_map(
         lambda x: x.shape,
         model_zoo.abstract_params(program.model_config(cell)))
@@ -34,14 +34,14 @@ def test_weights_fit_the_program_tree(cell):
 
 
 def test_weights_depend_on_every_bit_of_the_seed(cell):
-    a = weights.make(5, cell.dims)["embed"]
-    b = weights.make(5 + 2**33, cell.dims)["embed"]
+    a = weights.make(5, cell)["embed"]
+    b = weights.make(5 + 2**33, cell)["embed"]
     assert not np.allclose(np.asarray(a), np.asarray(b))
 
 
 def test_loss_and_gradients_match_the_program(cell):
     d = cell.dims
-    params = weights.make(7, d)
+    params = weights.make(7, cell)
     rng = np.random.default_rng(0)
     toks = rng.integers(0, d.vocab, (3, 33), dtype=np.int32)
     batch = {"tokens": toks[:, :-1], "labels": toks[:, 1:]}
@@ -49,7 +49,7 @@ def test_loss_and_gradients_match_the_program(cell):
     with jax.default_matmul_precision("highest"):
         (lp, _), gp = jax.value_and_grad(model.loss, has_aux=True)(
             params, batch)
-    r = ref.Reference(d, rows_per_block=2)
+    r = cell.arch("reference").Reference(d, rows_per_block=2)
     lr, gr = r.loss_and_grad(params, batch["tokens"], batch["labels"])
     assert abs(float(lp) - lr) < 1e-5 * lr
     for a, b in zip(jax.tree_util.tree_leaves(gp),
@@ -61,18 +61,17 @@ def test_loss_and_gradients_match_the_program(cell):
 def test_adamw_step_matches_the_program_optimizer(cell):
     from repro.configs.base import OptimizerConfig
     from repro.optim import transforms as tx
-    d = cell.dims
-    params = weights.make(8, d)
+    params = weights.make(8, cell)
     grads = jax.tree_util.tree_map(lambda p: 3.0 * jnp.sin(p), params)
     cfg = OptimizerConfig()
     chain = tx.build_optimizer(cfg)
     upd, _, _ = chain.update(grads, chain.init(params), params,
                              {"lr": 1e-3, "clip_scale": 1.0})
     want = tx.apply_updates(params, upd)
-    got, _, _ = ref.adamw_step(params, grads, ref.adamw_init(params),
-                               lr=1e-3, clip=cfg.grad_clip, b1=cfg.beta1,
-                               b2=cfg.beta2, eps=cfg.eps,
-                               weight_decay=cfg.weight_decay)
+    got, _, _ = common.adamw_step(params, grads, common.adamw_init(params),
+                                  lr=1e-3, clip=cfg.grad_clip, b1=cfg.beta1,
+                                  b2=cfg.beta2, eps=cfg.eps,
+                                  weight_decay=cfg.weight_decay)
     for a, b in zip(jax.tree_util.tree_leaves(want),
                     jax.tree_util.tree_leaves(got)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
