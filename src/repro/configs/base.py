@@ -27,6 +27,17 @@ class ModelConfig:
     # attention options
     qk_norm: bool = False
     qkv_bias: bool = False
+    # attention kind: "gqa" (q/k/v projections per head, n_kv_heads shared)
+    # | "mla" (DeepSeek-V2/V3 latent attention, training only: q_proj;
+    # keys and values from a kv_lora_rank-wide latent through an RMSNorm
+    # and kv_b_proj; RoPE on qk_rope_head_dim dims of q and of one k head
+    # shared by all heads; q/k heads qk_nope_head_dim + qk_rope_head_dim
+    # wide, v heads v_head_dim wide)
+    attn_kind: str = "gqa"
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
     # train/prefill attention backend:
     #   blockwise        – jnp online-softmax scan (the XLA oracle; default)
     #   flash            – Pallas flash-attention kernel (fwd + custom-VJP
@@ -50,14 +61,29 @@ class ModelConfig:
     norm_eps: float = 1e-5
     tie_embeddings: bool = False
     # MoE
+    # n_experts: the experts the router spans (its published width)
     n_experts: int = 0
     n_shared_experts: int = 0
     top_k: int = 0
     capacity_factor: float = 1.25
     # global: paper-era single global capacity buffer (pathological under
     # SPMD — see EXPERIMENTS.md §Perf); row_local: per-batch-row ranking,
-    # shard-local dispatch arithmetic (production default)
+    # shard-local dispatch arithmetic (production default); dropless: the
+    # expert-share layer (models/moe.py: no capacity, this chip computes
+    # only the experts it holds, through the moe_gmm kernels)
     moe_dispatch: str = "row_local"
+    # the experts held here (dropless only): ids expert_offset ..
+    # expert_offset + n_held_experts - 1 of the n_experts; 0 holds all
+    n_held_experts: int = 0
+    expert_offset: int = 0
+    # the dropless dispatch routes as DeepSeek-V3's noaux_tc (models/moe.py
+    # route): top-k of sigmoid scores plus a per-expert correction bias,
+    # weights normalised over the k, then scaled by routed_scaling_factor
+    routed_scaling_factor: float = 1.0
+    # leading dense layers (DeepSeek's first_k_dense_replace) and their
+    # MLP width; the n_layers - n_dense_layers after them are MoE
+    n_dense_layers: int = 0
+    dense_d_ff: int = 0
     router_aux_coef: float = 0.01
     router_z_coef: float = 1e-3
     # SSM (Mamba-2 / SSD)
@@ -92,6 +118,19 @@ class ModelConfig:
     max_seq_len: int = 532480  # generous default; shapes clamp per cell
     # numerics
     logits_softcap: float = 0.0
+
+    def __post_init__(self):
+        if self.moe_dispatch != "dropless" and (
+                self.n_held_experts or self.expert_offset
+                or self.routed_scaling_factor != 1.0):
+            raise ValueError(
+                f"{self.name}: n_held_experts, expert_offset and "
+                "routed_scaling_factor are read only by the dropless "
+                f"dispatch, not by {self.moe_dispatch!r}")
+
+    @property
+    def held_experts(self) -> int:
+        return self.n_held_experts or self.n_experts
 
     @property
     def resolved_head_dim(self) -> int:

@@ -1,7 +1,14 @@
 """moonshot-v1-16b-a3b (kimi/moonlight) — fine-grained MoE, 64 experts top-6.
 
-[hf:moonshotai/Moonlight-16B-A3B; hf]  48L d_model=2048 16H (GQA kv=16)
-d_ff=1408 (per expert) vocab=163840, MoE 64e top-6 (+2 shared experts).
+The repository's assignment block, not the published config: 48L
+d_model=2048 16H (GQA kv=16) d_ff=1408 (per expert) vocab=163840, MoE 64e
+top-6 (+2 shared experts), softmax top-k with capacity drops, no leading
+dense layer.  The published Moonlight-16B-A3B (hf:moonshotai/Moonlight-16B-A3B
+config.json, ``model_type`` deepseek_v3) has 27 layers, latent attention
+(MLA), one dense first layer of width 11264 and sigmoid routing with a
+correction bias; that block, cut to one chip's share, is
+``bench/configs/moonlight-16b-a3b-l5.json`` (``bench/programs/deepseek_v3.py``
+builds its ``ModelConfig``).
 """
 from repro.configs.base import ArchSpec, ModelConfig
 
@@ -21,6 +28,7 @@ MODEL = ModelConfig(
 
 SPEC = ArchSpec(
     model=MODEL,
-    source="hf:moonshotai/Moonlight-16B-A3B",
+    source="assignment block after hf:moonshotai/Moonlight-16B-A3B (not its "
+           "config.json; see bench/configs/moonlight-16b-a3b-l5.json)",
     notes="EP: experts sharded over the model axis; long_500k skipped: full attention",
 )

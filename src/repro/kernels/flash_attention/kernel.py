@@ -1,9 +1,11 @@
 """Pallas TPU flash attention: forward *and* backward (causal, GQA-expanded).
 
 Head-folded grid — every kernel takes a group of ``block_h`` heads per grid
-step: q, k, v, o, dO, dQ, dK and dV move in ``(block_h, block, D)`` blocks,
-the per-row ``lse`` and ``delta`` in ``(block_h, 1, block_q)`` blocks, and
-the products are batched over the head axis.  A grid step carries a fixed
+step: q, k, dQ and dK move in ``(block_h, block, D)`` blocks, v, o, dO and
+dV in ``(block_h, block, D_v)`` blocks (latent attention's values are
+narrower than its queries and keys; D_v = D otherwise), the per-row
+``lse`` and ``delta`` in ``(block_h, 1, block_q)`` blocks, and the products
+are batched over the head axis.  Scores are scaled by 1/sqrt(D).  A grid step carries a fixed
 cost on the chip (its DMA issue and wait, the pipeline's bookkeeping) that
 one 128 x 128 tile of one head does not cover; folding heads shares that
 cost among ``block_h`` tiles, and larger tiles share it further.
@@ -81,28 +83,35 @@ def _vmem_tile_bytes(rows: int, cols: int, itemsize: int) -> int:
 
 
 def vmem_bytes(block_h: int, block_q: int, block_k: int, d: int,
-               itemsize: int) -> int:
+               itemsize: int, d_v: int | None = None) -> int:
     """Scoped VMEM of the largest of the kernels at one tile: its blocks,
     double-buffered (at ``itemsize``; the fp32 lse and delta rows take one
     (1 x 128) tile per 128 lanes), its fp32 scratch, and the temporaries
     Mosaic keeps in VMEM, taken as one and a half fp32 (block_q, block_k)
     values: every tile of the sweep in PERF.md (section 5) that the
     compiler refused lies above the budget by this count.  The dK/dV
-    kernel is the largest unless ``block_q`` > 2 ``block_k``."""
+    kernel is the largest unless ``block_q`` > 2 ``block_k``.  Queries and
+    keys are ``d`` wide, values, outputs and their gradients ``d_v`` (``d``
+    where not given)."""
+    d_v = d if d_v is None else d_v
     tile = _vmem_tile_bytes
     tq, tk = tile(block_q, d, itemsize), tile(block_k, d, itemsize)
+    vq, vk = tile(block_q, d_v, itemsize), tile(block_k, d_v, itemsize)
     rows = 4 * (-(-block_q // 128) * 128)
-    fwd = 2 * (2 * tq + 2 * tk + rows) + 2 * tile(block_q, 1, 4) \
-        + tile(block_q, d, 4)                   # q, o; k, v; lse | m, l, acc
-    dq = 2 * (3 * tq + 2 * tk + 2 * rows) + tile(block_q, d, 4)
-    dkv = 2 * (2 * tq + 4 * tk + 2 * rows) + 2 * tile(block_k, d, 4)
+    fwd = 2 * (tq + vq + tk + vk + rows) + 2 * tile(block_q, 1, 4) \
+        + tile(block_q, d_v, 4)                 # q, o; k, v; lse | m, l, acc
+    dq = 2 * (2 * tq + vq + tk + vk + 2 * rows) + tile(block_q, d, 4)
+    dkv = 2 * (tq + vq + 2 * tk + 2 * vk + 2 * rows) + tile(block_k, d, 4) \
+        + tile(block_k, d_v, 4)
     values = 3 * tile(block_q, block_k, 4) // 2
     return block_h * (max(fwd, dq, dkv) + values)
 
 
 def tiles(bh: int, s: int, d: int, itemsize: int, block_q: int | None = None,
-          block_k: int | None = None) -> tuple[int, int, int]:
-    """(block_h, block_q, block_k) for (bh, s, d) operands of ``itemsize``.
+          block_k: int | None = None, d_v: int | None = None
+          ) -> tuple[int, int, int]:
+    """(block_h, block_q, block_k) for (bh, s, d) queries and keys and
+    (bh, s, d_v) values (``d_v`` of None: ``d``) of ``itemsize``.
 
     With neither sequence block given, both are the first of the whole
     sequence and ``BLOCKS`` whose kernels fit ``VMEM_BUDGET`` with two heads
@@ -114,7 +123,7 @@ def tiles(bh: int, s: int, d: int, itemsize: int, block_q: int | None = None,
     kernels fit ``VMEM_BUDGET`` (``vmem_bytes``).
     """
     def fits(h, bq, bk):
-        return vmem_bytes(h, bq, bk, d, itemsize) <= VMEM_BUDGET
+        return vmem_bytes(h, bq, bk, d, itemsize, d_v) <= VMEM_BUDGET
 
     if block_q is None and block_k is None:
         block_q = block_k = next(
@@ -233,15 +242,17 @@ def flash_attention_fwd(q: jax.Array, k: jax.Array, v: jax.Array, *,
                         block_q: int = 128, block_k: int = 128,
                         valid_len: int = 0, interpret: bool = False
                         ) -> tuple[jax.Array, jax.Array]:
-    """q, k, v: (BH, S, D) (GQA repeat handled by ops.py).
+    """q, k: (BH, S, D); v: (BH, S, D_v) (GQA repeat handled by ops.py).
 
-    Returns (o (BH, S, D), lse (BH, 1, S) fp32).  `valid_len` masks padded
+    Returns (o (BH, S, D_v), lse (BH, 1, S) fp32); scores are scaled by
+    1/sqrt(D).  `valid_len` masks padded
     tail keys (0 = none).  The per-row lse and delta are stored one row of
     lanes per head, ``(BH, 1, S)`` with ``(block_h, 1, block_q)`` blocks:
     the chip tiles a block's last two dimensions by (8, 128), and a
     ``(block_h, block_q)`` block of a ``(BH, S)`` array is not such a tile.
     """
     bh, s, d = q.shape
+    d_v = v.shape[-1]
     block_q = min(block_q, s)
     block_k = min(block_k, s)
     assert s % block_q == 0 and s % block_k == 0, (s, block_q, block_k)
@@ -263,20 +274,20 @@ def flash_attention_fwd(q: jax.Array, k: jax.Array, v: jax.Array, *,
         in_specs=[
             pl.BlockSpec((block_h, block_q, d), q_map),
             pl.BlockSpec((block_h, block_k, d), kv_map),
-            pl.BlockSpec((block_h, block_k, d), kv_map),
+            pl.BlockSpec((block_h, block_k, d_v), kv_map),
         ],
         out_specs=[
-            pl.BlockSpec((block_h, block_q, d), q_map),
+            pl.BlockSpec((block_h, block_q, d_v), q_map),
             pl.BlockSpec((block_h, 1, block_q), lambda b, qi, ki: (b, 0, qi)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, s, d), q.dtype),
+            jax.ShapeDtypeStruct((bh, s, d_v), q.dtype),
             jax.ShapeDtypeStruct((bh, 1, s), jnp.float32),
         ],
         scratch_shapes=[
             _vmem((block_h, block_q, 1), jnp.float32),  # m: running row max
             _vmem((block_h, block_q, 1), jnp.float32),  # l: running row sum
-            _vmem((block_h, block_q, d), jnp.float32),  # acc: weighted values
+            _vmem((block_h, block_q, d_v), jnp.float32),  # acc: weighted values
         ],
         interpret=interpret,
         name="flash_attention_fwd",
@@ -379,10 +390,11 @@ def flash_attention_bwd(q: jax.Array, k: jax.Array, v: jax.Array,
                         ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """Backward pass at the flattened (BH, S, D) layout.
 
-    q, k, v, o, do: (BH, S, D); lse: (BH, 1, S) fp32 from the forward.
-    Returns (dq, dk, dv) with the input dtypes.
+    q, k: (BH, S, D); v, o, do: (BH, S, D_v); lse: (BH, 1, S) fp32 from
+    the forward.  Returns (dq, dk, dv) with the input dtypes and shapes.
     """
     bh, s, d = q.shape
+    d_v = v.shape[-1]
     block_q = min(block_q, s)
     block_k = min(block_k, s)
     assert s % block_q == 0 and s % block_k == 0, (s, block_q, block_k)
@@ -398,8 +410,8 @@ def flash_attention_bwd(q: jax.Array, k: jax.Array, v: jax.Array,
         _bwd_preprocess_kernel,
         grid=(groups, q_blocks),
         in_specs=[
-            pl.BlockSpec((block_h, block_q, d), lambda b, qi: (b, qi, 0)),
-            pl.BlockSpec((block_h, block_q, d), lambda b, qi: (b, qi, 0)),
+            pl.BlockSpec((block_h, block_q, d_v), lambda b, qi: (b, qi, 0)),
+            pl.BlockSpec((block_h, block_q, d_v), lambda b, qi: (b, qi, 0)),
         ],
         out_specs=pl.BlockSpec((block_h, 1, block_q),
                                lambda b, qi: (b, 0, qi)),
@@ -420,8 +432,8 @@ def flash_attention_bwd(q: jax.Array, k: jax.Array, v: jax.Array,
         in_specs=[
             pl.BlockSpec((block_h, block_q, d), q_map3),
             pl.BlockSpec((block_h, block_k, d), kv_map),
-            pl.BlockSpec((block_h, block_k, d), kv_map),
-            pl.BlockSpec((block_h, block_q, d), q_map3),
+            pl.BlockSpec((block_h, block_k, d_v), kv_map),
+            pl.BlockSpec((block_h, block_q, d_v), q_map3),
             pl.BlockSpec((block_h, 1, block_q), q_row3),
             pl.BlockSpec((block_h, 1, block_q), q_row3),
         ],
@@ -443,22 +455,22 @@ def flash_attention_bwd(q: jax.Array, k: jax.Array, v: jax.Array,
         in_specs=[
             pl.BlockSpec((block_h, block_q, d), q_clamp),
             pl.BlockSpec((block_h, block_k, d), kv_map2),
-            pl.BlockSpec((block_h, block_k, d), kv_map2),
-            pl.BlockSpec((block_h, block_q, d), q_clamp),
+            pl.BlockSpec((block_h, block_k, d_v), kv_map2),
+            pl.BlockSpec((block_h, block_q, d_v), q_clamp),
             pl.BlockSpec((block_h, 1, block_q), q_row_clamp),
             pl.BlockSpec((block_h, 1, block_q), q_row_clamp),
         ],
         out_specs=[
             pl.BlockSpec((block_h, block_k, d), kv_map2),
-            pl.BlockSpec((block_h, block_k, d), kv_map2),
+            pl.BlockSpec((block_h, block_k, d_v), kv_map2),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((bh, s, d), k.dtype),
-            jax.ShapeDtypeStruct((bh, s, d), v.dtype),
+            jax.ShapeDtypeStruct((bh, s, d_v), v.dtype),
         ],
         scratch_shapes=[
             _vmem((block_h, block_k, d), jnp.float32),
-            _vmem((block_h, block_k, d), jnp.float32),
+            _vmem((block_h, block_k, d_v), jnp.float32),
         ],
         interpret=interpret,
         name="flash_attention_dkv",

@@ -1,7 +1,8 @@
 """Differentiable jit'd public wrapper for the flash-attention kernels.
 
 ``flash_attention`` is a ``jax.custom_vjp`` at the model-facing layout
-(q: (B, S, H, D); k, v: (B, S, KV, D) with H = KV * G):
+(q: (B, S, H, D); k: (B, S, KV, D); v: (B, S, KV, D_v) with H = KV * G;
+D_v = D but for latent attention):
 
 * tiles: ``kernel.tiles`` chooses ``(block_h, block_q, block_k)`` from
   the shapes (B*H, S, D and the itemsize): sequence blocks of the whole
@@ -62,7 +63,8 @@ def _prep(q, k, v, block_q, block_k):
     """
     b, s, h, d = q.shape
     g = h // k.shape[2]
-    blocks = tiles(b * h, s, d, q.dtype.itemsize, block_q, block_k)
+    blocks = tiles(b * h, s, d, q.dtype.itemsize, block_q, block_k,
+                   d_v=v.shape[-1])
     # the padded length must be divisible by *both* blocks, not just the
     # larger one (e.g. s=96, bq=64, bk=96 needs lcm padding, not zero)
     pad = (-s) % math.lcm(*blocks[1:])
@@ -89,7 +91,7 @@ def _flash_fwd(q, k, v, causal, block_q, block_k, interpret):
 def _flash_bwd(causal, block_q, block_k, interpret, res, do):
     q, k, v, out, lse = res
     b, s, _, d = q.shape
-    kv = k.shape[2]
+    kv, d_v = k.shape[2], v.shape[-1]
     g, (hg, bq, bk), pad, qf, kf, vf = _prep(q, k, v, block_q, block_k)
     of = _flatten(out, 1, pad)
     dof = _flatten(do, 1, pad)
@@ -102,7 +104,7 @@ def _flash_bwd(causal, block_q, block_k, interpret, res, do):
     dk = (_unflatten(dkf, b, s).astype(jnp.float32)
           .reshape(b, s, kv, g, d).sum(axis=3))
     dv = (_unflatten(dvf, b, s).astype(jnp.float32)
-          .reshape(b, s, kv, g, d).sum(axis=3))
+          .reshape(b, s, kv, g, d_v).sum(axis=3))
     return dq, dk.astype(k.dtype), dv.astype(v.dtype)
 
 
@@ -115,7 +117,8 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = True, block_q: int | None = None,
                     block_k: int | None = None,
                     interpret: bool | None = None) -> jax.Array:
-    """q: (B, S, H, D); k, v: (B, S, KV, D) with H = KV * G. Returns like q.
+    """q: (B, S, H, D); k: (B, S, KV, D); v: (B, S, KV, D_v) with
+    H = KV * G.  Returns (B, S, H, D_v); scores are scaled by 1/sqrt(D).
 
     ``block_q`` / ``block_k`` of None are chosen from the shapes
     (``kernel.tiles``).

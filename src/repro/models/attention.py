@@ -1,4 +1,5 @@
-"""GQA attention: flash/blockwise train-prefill backends + cached decode.
+"""GQA and latent (MLA) attention: flash/blockwise train-prefill backends
++ cached decode (GQA only).
 
 Two train/prefill backends, selected by ``ModelConfig.attn_backend``:
 
@@ -31,6 +32,8 @@ NEG_INF = -1e30
 
 
 def attention_def(cfg: ModelConfig) -> Dict[str, ParamDef]:
+    if cfg.attn_kind == "mla":
+        return mla_def(cfg)
     d, h, kv, hd = cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.resolved_head_dim
     s = 1.0 / math.sqrt(d)
     s_o = 1.0 / math.sqrt(h * hd)
@@ -48,6 +51,67 @@ def attention_def(cfg: ModelConfig) -> Dict[str, ParamDef]:
         defs["q_norm"] = ParamDef((hd,), ("head_dim",), "ones")
         defs["k_norm"] = ParamDef((hd,), ("head_dim",), "ones")
     return defs
+
+
+def mla_def(cfg: ModelConfig) -> Dict[str, ParamDef]:
+    """Latent attention (DeepSeek-V2/V3, no q LoRA): ``wq`` is q_proj,
+    ``wkv_a`` kv_a_proj_with_mqa (the latent and the shared rotary key),
+    ``kv_norm`` the latent's RMSNorm, ``wkv_b`` kv_b_proj (each head's
+    non-rotary key and value), ``wo`` o_proj."""
+    d, h, r = cfg.d_model, cfg.n_heads, cfg.kv_lora_rank
+    dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    s = 1.0 / math.sqrt(d)
+    return {
+        "wq": ParamDef((d, h, dn + dr), ("embed", "heads", "head_dim"),
+                       "normal", s),
+        "wkv_a": ParamDef((d, r + dr), ("embed", None), "normal", s),
+        "kv_norm": {"scale": ParamDef((r,), (None,), "ones")},
+        "wkv_b": ParamDef((r, h, dn + dv), (None, "heads", "head_dim"),
+                          "normal", 1.0 / math.sqrt(r)),
+        "wo": ParamDef((h, dv, d), ("heads", "head_dim", "embed"), "normal",
+                       1.0 / math.sqrt(h * dv)),
+    }
+
+
+def _rope_pairs(x: jax.Array, positions: jax.Array, theta: float
+                ) -> jax.Array:
+    """DeepSeek's rotary convention: the dims are pairs (2i, 2i + 1),
+    de-interleaved to (evens, odds) and then rotated half against half."""
+    d = x.shape[-1]
+    x = x.reshape(x.shape[:-1] + (d // 2, 2)).swapaxes(-1, -2)
+    return apply_rope(x.reshape(x.shape[:-2] + (d,)), positions, theta)
+
+
+def mla_attention(params, x: jax.Array, cfg: ModelConfig,
+                  positions: jax.Array, block_kv: int) -> jax.Array:
+    """Causal latent attention for training: queries and keys are
+    qk_nope_head_dim + qk_rope_head_dim wide, the rotary part of the key
+    one head shared by all, values v_head_dim wide; the context runs
+    through ``_context`` (flash with d_qk != d_v), scaled by 1/sqrt(d_qk)."""
+    r, dn = cfg.kv_lora_rank, cfg.qk_nope_head_dim
+    q = jnp.einsum("bsd,dhk->bshk", x, params["wq"].astype(x.dtype))
+    kv_a = jnp.einsum("bsd,dr->bsr", x, params["wkv_a"].astype(x.dtype))
+    latent = rms_norm(kv_a[..., :r], params["kv_norm"]["scale"],
+                      cfg.norm_eps)
+    kv = jnp.einsum("bsr,rhk->bshk", latent, params["wkv_b"].astype(x.dtype))
+    k_nope, v = kv[..., :dn], kv[..., dn:]
+    q_pe = _rope_pairs(q[..., dn:], positions, cfg.rope_theta)
+    k_pe = _rope_pairs(kv_a[..., None, r:], positions, cfg.rope_theta)
+    q = jnp.concatenate([q[..., :dn], q_pe], axis=-1)
+    k = jnp.concatenate(
+        [k_nope, jnp.broadcast_to(k_pe, k_nope.shape[:-1] + k_pe.shape[-1:])],
+        axis=-1)
+    ctx = _context(q, k, v, cfg, block_kv)
+    return jnp.einsum("bshk,hkd->bsd", ctx, params["wo"].astype(x.dtype))
+
+
+def require_kv_cache(cfg: ModelConfig) -> None:
+    """Serving reads and writes a KV cache; latent attention has none."""
+    if cfg.attn_kind == "mla":
+        raise NotImplementedError(
+            f"{cfg.name}: latent attention (attn_kind='mla') is trained "
+            "only; serving it (prefill, decode) needs a latent KV cache, "
+            "which the program does not have")
 
 
 def _project_qkv(params, x, cfg: ModelConfig, positions):
@@ -72,11 +136,12 @@ def blockwise_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                         q_offset: int = 0) -> jax.Array:
     """Online-softmax attention scanning over KV blocks.
 
-    q: (B, Sq, H, D); k, v: (B, Sk, KV, D) with H = KV * G.
+    q: (B, Sq, H, D); k: (B, Sk, KV, D); v: (B, Sk, KV, D_v) with
+    H = KV * G.  Returns (B, Sq, H, D_v).
     Memory high-water is O(Sq * block_kv) per head instead of O(Sq * Sk).
     """
     b, sq, h, d = q.shape
-    sk, kvh = k.shape[1], k.shape[2]
+    sk, kvh, d_v = k.shape[1], k.shape[2], v.shape[-1]
     g = h // kvh
     scale = 1.0 / math.sqrt(d)
     # odd bucket/remainder lengths (e.g. sk=544 against the default 512)
@@ -95,7 +160,7 @@ def blockwise_attention(q: jax.Array, k: jax.Array, v: jax.Array,
 
     qg = q.reshape(b, sq, kvh, g, d) * scale
     kb = k.reshape(b, n_blocks, block_kv, kvh, d)
-    vb = v.reshape(b, n_blocks, block_kv, kvh, d)
+    vb = v.reshape(b, n_blocks, block_kv, kvh, d_v)
     q_pos = q_offset + jnp.arange(sq)
 
     def step(carry, inp):
@@ -121,13 +186,13 @@ def blockwise_attention(q: jax.Array, k: jax.Array, v: jax.Array,
 
     m0 = jnp.full((b, kvh, g, sq), NEG_INF, jnp.float32)
     l0 = jnp.zeros((b, kvh, g, sq), jnp.float32)
-    acc0 = jnp.zeros((b, kvh, g, sq, d), jnp.float32)
+    acc0 = jnp.zeros((b, kvh, g, sq, d_v), jnp.float32)
     kb_t = jnp.moveaxis(kb, 1, 0)  # (n_blocks, B, blk, KV, D)
     vb_t = jnp.moveaxis(vb, 1, 0)
     (m, l, acc), _ = jax.lax.scan(
         step, (m0, l0, acc0), (kb_t, vb_t, jnp.arange(n_blocks)))
     out = acc / jnp.maximum(l[..., None], 1e-30)
-    out = out.transpose(0, 3, 1, 2, 4).reshape(b, sq, h, d)
+    out = out.transpose(0, 3, 1, 2, 4).reshape(b, sq, h, d_v)
     return out.astype(q.dtype)
 
 
@@ -156,13 +221,16 @@ def _context(q: jax.Array, k: jax.Array, v: jax.Array, cfg: ModelConfig,
 def full_attention(params: Dict[str, jax.Array], x: jax.Array, cfg: ModelConfig,
                    positions: jax.Array, block_kv: int = 512
                    ) -> Tuple[jax.Array, Tuple[jax.Array, jax.Array]]:
-    """Causal self-attention over the whole sequence. Returns (out, (k, v)).
+    """Causal self-attention over the whole sequence. Returns (out, (k, v));
+    latent attention returns (out, None).
 
     Routes through ``cfg.attn_backend`` (see ``_context``): the training
     step (``jax.value_and_grad`` in launch/steps.py) and the serve prefill
     both reach the Pallas kernel — forward *and* backward — when "flash" is
     selected on TPU.
     """
+    if cfg.attn_kind == "mla":  # no KV cache (see ``require_kv_cache``)
+        return mla_attention(params, x, cfg, positions, block_kv), None
     q, k, v = _project_qkv(params, x, cfg, positions)
     ctx = _context(q, k, v, cfg, block_kv)
     out = jnp.einsum("bshk,hkd->bsd", ctx, params["wo"].astype(x.dtype))
@@ -213,6 +281,7 @@ def decode_attention(params: Dict[str, jax.Array], x: jax.Array,
     interpret mode — CPU validation).  The kernel serves the single-token
     step; multi-token calls stay on the reference path.
     """
+    require_kv_cache(cfg)
     b, s_q, h, = x.shape[0], x.shape[1], cfg.n_heads
     pos = jnp.asarray(pos)
     per_slot = pos.ndim == 1

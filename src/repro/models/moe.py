@@ -1,11 +1,18 @@
 """Fine-grained MoE (DeepSeekMoE / Moonlight style): shared + routed experts.
 
-Top-k token-choice routing with a capacity buffer.  Dispatch is sort-free:
-the position of each (token, expert) assignment inside its expert's capacity
-buffer is a cumulative count over a one-hot matrix — static shapes, scatter +
-gather, TPU/XLA-SPMD friendly.  Experts are sharded over the ``model`` mesh
-axis (EP); the scatter/gather across the token-sharded <-> expert-sharded
-boundary lowers to all-to-all-style collectives under SPMD.
+Two capacity dispatches: top-k token-choice routing with a capacity
+buffer, sort-free — the position of each (token, expert) assignment inside
+its expert's capacity buffer is a cumulative count over a one-hot matrix —
+static shapes, scatter + gather, TPU/XLA-SPMD friendly.  Experts are
+sharded over the ``model`` mesh axis (EP); the scatter/gather across the
+token-sharded <-> expert-sharded boundary lowers to all-to-all-style
+collectives under SPMD.
+
+And one dropless expert-share dispatch (``moe_ffn_dropless``): the layer
+holds ``n_held_experts`` of the router's ``n_experts``, routes over all of
+them, sorts the assignments by expert and computes only its own experts'
+rows through grouped matrix products (``repro.kernels.moe_gmm``), whose
+work follows the rows routed here.  No assignment is dropped.
 """
 from __future__ import annotations
 
@@ -18,6 +25,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.distributed.sharding import constrain
+from repro.kernels.moe_gmm.ops import gmm
 from repro.models import attention as attn_mod
 from repro.models.layers import (
     ParamDef, advance_pos, apply_norm, cast, cross_entropy_loss,
@@ -28,14 +36,17 @@ from repro.models.transformer import DenseLM, _logits, embed_inputs
 
 def moe_ffn_def(cfg: ModelConfig) -> Dict[str, Any]:
     d, f, e = cfg.d_model, cfg.d_ff, cfg.n_experts
+    held = cfg.held_experts
     s_in = 1.0 / math.sqrt(d)
     s_out = 1.0 / math.sqrt(f)
     defs: Dict[str, Any] = {
         "router": ParamDef((d, e), ("embed", "experts"), "normal", s_in),
-        "w_gate": ParamDef((e, d, f), ("experts", "embed", "mlp"), "normal", s_in),
-        "w_up": ParamDef((e, d, f), ("experts", "embed", "mlp"), "normal", s_in),
-        "w_down": ParamDef((e, f, d), ("experts", "mlp", "embed"), "normal", s_out),
+        "w_gate": ParamDef((held, d, f), ("experts", "embed", "mlp"), "normal", s_in),
+        "w_up": ParamDef((held, d, f), ("experts", "embed", "mlp"), "normal", s_in),
+        "w_down": ParamDef((held, f, d), ("experts", "mlp", "embed"), "normal", s_out),
     }
+    if cfg.moe_dispatch == "dropless":
+        defs["router_bias"] = ParamDef((e,), ("experts",), "zeros")
     if cfg.n_shared_experts:
         defs["shared"] = mlp_def(d, cfg.d_ff * cfg.n_shared_experts, "swiglu")
     return defs
@@ -176,7 +187,69 @@ def moe_ffn_rowlocal(params, x: jax.Array, cfg: ModelConfig
     return y, aux
 
 
+def route(params, xt: jax.Array, cfg: ModelConfig
+          ) -> Tuple[jax.Array, jax.Array]:
+    """(T, D) tokens -> (weights, expert ids), each (T, top_k): the router
+    in float32 at full precision over all ``n_experts``, as DeepSeek-V3's
+    noaux_tc with one group: experts chosen by sigmoid score plus
+    ``router_bias``, weighted by their sigmoid scores; the bias takes no
+    gradient.  The weights are normalised over the k, then scaled by
+    ``routed_scaling_factor``."""
+    logits = jnp.dot(xt.astype(jnp.float32),
+                     params["router"].astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    scores = jax.nn.sigmoid(logits)
+    _, idx = jax.lax.top_k(
+        scores + params["router_bias"].astype(jnp.float32), cfg.top_k)
+    gate = jnp.take_along_axis(scores, idx, axis=-1)
+    gate = gate / (gate.sum(-1, keepdims=True) + 1e-20)
+    return gate * cfg.routed_scaling_factor, idx
+
+
+def moe_ffn_dropless(params, x: jax.Array, cfg: ModelConfig
+                     ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+    """The held experts' share of the layer, plus the shared experts.
+
+    The T x top_k assignments are sorted by expert; the held experts'
+    SwiGLU runs as grouped products over their rows only, and each row,
+    weighted, is added back to its token.  What the experts held elsewhere
+    would add is left out: on one chip of an expert-parallel deployment
+    that is their chips' part of the exchange.  Counters: ``held_rows``
+    (assignments computed here) and ``load_max`` (the fullest held expert's
+    rows over the held experts' mean)."""
+    b, s, d = x.shape
+    t, k = b * s, cfg.top_k
+    off, held = cfg.expert_offset, cfg.held_experts
+    xt = x.reshape(t, d)
+    gate, idx = route(params, xt, cfg)
+
+    flat = idx.reshape(-1)
+    order = jnp.argsort(flat)
+    sizes = jnp.bincount(flat, length=cfg.n_experts).astype(jnp.int32)
+    tok = order // k
+    xs = jnp.take(xt, tok, axis=0)
+    wg = params["w_gate"].astype(x.dtype)
+    wu = params["w_up"].astype(x.dtype)
+    wd = params["w_down"].astype(x.dtype)
+    w = jnp.take(gate.reshape(-1), order).astype(x.dtype)
+    # each row weighted before the down product, not after it: its
+    # backward pass then keeps the (T * top_k, F) rows it needs anyway, not
+    # the (T * top_k, D) outputs as well
+    h = jax.nn.silu(gmm(xs, wg, sizes, off)) * gmm(xs, wu, sizes, off)
+    ys = gmm(h * w[:, None], wd, sizes, off)  # zero outside the held rows
+    y = jnp.zeros_like(xt).at[tok].add(ys)
+
+    if cfg.n_shared_experts:
+        y = y + mlp_apply(params["shared"], xt, "swiglu")
+    rows = sizes[off:off + held].astype(jnp.float32)
+    aux = {"held_rows": rows.sum(),
+           "load_max": rows.max() / jnp.maximum(rows.mean(), 1.0)}
+    return y.reshape(b, s, d), aux
+
+
 def apply_moe_ffn(params, x, cfg: ModelConfig):
+    if cfg.moe_dispatch == "dropless":
+        return moe_ffn_dropless(params, x, cfg)
     if cfg.moe_dispatch == "row_local":
         return moe_ffn_rowlocal(params, x, cfg)
     return moe_ffn(params, x, cfg)
@@ -193,18 +266,27 @@ def moe_defs(cfg: ModelConfig) -> Dict[str, Any]:
     }
     defs: Dict[str, Any] = {
         "embed": ParamDef((pv, d), ("vocab", "embed"), "embed", 0.02),
-        "layers": stack_defs(cfg.n_layers, layer),
+        "layers": stack_defs(cfg.n_layers - cfg.n_dense_layers, layer),
         "final_norm": norm_def(d, cfg.norm),
         "lm_head": ParamDef((d, pv), ("embed", "vocab"), "normal",
                             1.0 / math.sqrt(d)),
     }
+    if cfg.n_dense_layers:
+        dense = {"ln1": norm_def(d, cfg.norm),
+                 "attn": attn_mod.attention_def(cfg),
+                 "ln2": norm_def(d, cfg.norm),
+                 "mlp": mlp_def(d, cfg.dense_d_ff, cfg.mlp)}
+        defs["dense_layers"] = stack_defs(cfg.n_dense_layers, dense)
     return defs
 
 
 @dataclass
 class MoELM(DenseLM):
     """MoE decoder — reuses the dense attention/serving skeleton, swaps the
-    FFN for shared+routed experts and adds router aux losses."""
+    FFN for shared+routed experts and adds router aux losses (none for the
+    dropless dispatch: its loss is the cross-entropy alone).  Leading
+    dense layers (``n_dense_layers``, MLP width ``dense_d_ff``) run first,
+    from their own parameter stack ``dense_layers``."""
 
     def _moe_block(self, collect_kv: bool):
         cfg = self.cfg
@@ -230,6 +312,11 @@ class MoELM(DenseLM):
         x = constrain(x, ("batch", "seq", "embed"))
         block = maybe_checkpoint(self._moe_block(collect_kv=False), self.remat)
 
+        if cfg.n_dense_layers:
+            dense = maybe_checkpoint(self._block_nokv(), self.remat)
+            x, _ = maybe_scan(lambda c, lp: (dense(c, lp, positions), None),
+                              x, params["dense_layers"], self.unroll_layers)
+
         def body(carry, lp):
             return block(carry, lp, positions)
 
@@ -239,6 +326,10 @@ class MoELM(DenseLM):
             logits = logits[:, batch["patch_embeds"].shape[1]:, :]
         loss, denom = cross_entropy_loss(
             logits, batch["labels"], batch.get("loss_mask"), cfg.vocab_size)
+        if cfg.moe_dispatch == "dropless":
+            return loss, {"loss": loss, "tokens": denom,
+                          "moe_held_rows": aux["held_rows"].sum(),
+                          "moe_load_max": aux["load_max"].max()}
         lb = aux["load_balance"].mean()
         rz = aux["router_z"].mean()
         total = loss + cfg.router_aux_coef * lb + cfg.router_z_coef * rz
@@ -246,7 +337,15 @@ class MoELM(DenseLM):
                        "router_z": rz,
                        "dropped_frac": aux["dropped_frac"].mean()}
 
+    def _require_servable(self):
+        attn_mod.require_kv_cache(self.cfg)
+        if self.cfg.n_dense_layers:
+            raise NotImplementedError(
+                f"{self.cfg.name}: serving skips no layer, and the serving "
+                "path has no leading dense layers")
+
     def prefill(self, params, batch, cache_len=None):
+        self._require_servable()
         cfg = self.cfg
         params = cast(params, self.dtype)
         x, positions = embed_inputs(params, batch, cfg, self.dtype)
@@ -271,6 +370,7 @@ class MoELM(DenseLM):
         return logits, cache
 
     def decode(self, params, cache, tokens):
+        self._require_servable()
         cfg = self.cfg
         params = cast(params, self.dtype)
         pos = cache["pos"]

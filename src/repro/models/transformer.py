@@ -131,6 +131,7 @@ class DenseLM:
     def prefill(self, params, batch, cache_len: Optional[int] = None):
         """Full forward; returns (last-position logits, KV cache)."""
         cfg = self.cfg
+        attn_mod.require_kv_cache(cfg)
         params = cast(params, self.dtype)
         x, positions = embed_inputs(params, batch, cfg, self.dtype)
         s = x.shape[1]

@@ -188,6 +188,62 @@ def test_flash_tiles_adapt_to_the_shapes(bh, s, itemsize, want):
         assert vmem_bytes(*want, 64, itemsize) <= VMEM_BUDGET
 
 
+# latent attention: queries and keys d_qk wide, values d_v (Moonlight's
+# 192 / 128 among them, at a short sequence)
+@pytest.mark.parametrize("b,s,h,kv,d,d_v,causal", [
+    (1, 64, 4, 2, 48, 32, True),
+    (2, 136, 4, 4, 24, 16, True),     # padded tail, several blocks
+    (1, 96, 2, 1, 32, 64, False),     # values wider than keys
+    (1, 128, 2, 2, 192, 128, True),   # Moonlight's head widths
+])
+def test_flash_attention_narrow_values_match_reference(b, s, h, kv, d, d_v,
+                                                       causal):
+    ks = jax.random.split(jax.random.PRNGKey(11 * s + d), 4)
+    q = jax.random.normal(ks[0], (b, s, h, d))
+    k = jax.random.normal(ks[1], (b, s, kv, d))
+    v = jax.random.normal(ks[2], (b, s, kv, d_v))
+    w = jax.random.normal(ks[3], (b, s, h, d_v))
+
+    def fa(q, k, v):
+        return flash_attention(q, k, v, causal=causal, block_q=64,
+                               block_k=64, interpret=True)
+
+    def ref(q, k, v):
+        return attention_reference_gqa(q, k, v, causal=causal)
+
+    out = fa(q, k, v)
+    assert out.shape == (b, s, h, d_v)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref(q, k, v)),
+                               atol=2e-5, rtol=2e-5)
+    g = jax.grad(lambda *a: jnp.sum(fa(*a) * w), (0, 1, 2))(q, k, v)
+    gr = jax.grad(lambda *a: jnp.sum(ref(*a) * w), (0, 1, 2))(q, k, v)
+    for name, a, b_ in zip(("dq", "dk", "dv"), g, gr):
+        assert a.shape == b_.shape, name
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b_), atol=1e-4,
+                                   rtol=1e-4, err_msg=name)
+
+
+def test_flash_tiles_with_narrow_values():
+    """Where d_v = d the VMEM count and the tiles are the ones computed
+    without a d_v (GPT-2's cells keep their tiles); Moonlight's 192 / 128
+    heads at 8192 take 512 x 512 tiles of two heads, within the budget."""
+    from repro.kernels.flash_attention.kernel import (VMEM_BUDGET, tiles,
+                                                      vmem_bytes)
+    for bq, bk in ((8, 8), (136, 136), (512, 512), (1024, 1024), (64, 128)):
+        for d in (16, 64, 192):
+            for item in (2, 4):
+                assert vmem_bytes(3, bq, bk, d, item, d_v=d) == \
+                    vmem_bytes(3, bq, bk, d, item)
+    for bh, s in ((384, 1024), (200, 1024), (96, 136), (12, 1024)):
+        assert tiles(bh, s, 64, 4, d_v=64) == tiles(bh, s, 64, 4)
+    assert tiles(200, 1024, 64, 4) == (2, 512, 512)   # gpt2-1.5b-l12
+    assert tiles(16, 8192, 192, 4, d_v=128) == (2, 512, 512)
+    assert vmem_bytes(2, 512, 512, 192, 4, d_v=128) <= VMEM_BUDGET
+    # the narrower values take less than keys as wide as the queries
+    assert vmem_bytes(2, 512, 512, 192, 4, d_v=128) < \
+        vmem_bytes(2, 512, 512, 192, 4)
+
+
 def test_flash_tiles_keep_given_blocks():
     """Blocks passed in win (clamped to the sequence); a block given alone
     pairs with 128; the head group is still chosen for them."""

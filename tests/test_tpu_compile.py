@@ -20,6 +20,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.kernels import (flash_attention, flash_decode, flash_decode_paged,
                            ssd, wkv6)
+from repro.kernels.moe_gmm.ops import gmm
 
 KERNEL_OP = "tpu_custom_call"
 BLOCK_RULE = ("last two dimensions of your block shape are divisible by 8 "
@@ -108,6 +109,38 @@ def test_flash_attention_kernels_carry_stable_names(one_chip):
                      "flash_attention_dq", "flash_attention_fwd",
                      "flash_attention_fwd"]
     assert all("flash_attention" in n for n in names)
+
+
+# moonlight-16b-a3b's latent attention: 16 heads, queries and keys of
+# 128 + 64, values of 128, one row of 8192 tokens; under remat full the
+# forward runs twice
+def test_flash_attention_compiles_at_latent_widths(one_chip):
+    attn = jax.checkpoint(
+        lambda q, k, v: flash_attention(q, k, v, causal=True,
+                                        interpret=False))
+    step = jax.value_and_grad(lambda q, k, v: attn(q, k, v).sum(),
+                              argnums=(0, 1, 2))
+    qk = _sds(one_chip, (1, 8192, 16, 192))
+    v = _sds(one_chip, (1, 8192, 16, 128))
+    names = _kernel_names(_compile_for_chip(step, qk, qk, v))
+    assert names == ["flash_attention_delta", "flash_attention_dkv",
+                     "flash_attention_dq", "flash_attention_fwd",
+                     "flash_attention_fwd"]
+
+
+# the held experts' products of one moonlight-16b-a3b MoE layer at 1 x 8192
+# tokens: 6 choices each, rows sorted over the router's 64 experts, 8 of
+# them (1408 wide) held here from expert 8; forward and backward
+@pytest.mark.parametrize("k,n", [(2048, 1408), (1408, 2048)])
+def test_moe_gmm_compiles_and_carries_stable_names(one_chip, k, n):
+    def loss(x, w, sizes):
+        return gmm(x, w, sizes, 8, interpret=False).sum()
+
+    step = jax.value_and_grad(loss, argnums=(0, 1))
+    names = _kernel_names(_compile_for_chip(
+        step, _sds(one_chip, (8192 * 6, k)), _sds(one_chip, (8, k, n)),
+        _sds(one_chip, (64,), jnp.int32)))
+    assert names == ["moe_gmm", "moe_gmm", "moe_tgmm"]
 
 
 def test_flash_decode_compiles(one_chip):
