@@ -16,6 +16,7 @@ work follows the rows routed here.  No assignment is dropped.
 """
 from __future__ import annotations
 
+import functools
 import math
 from dataclasses import dataclass
 from typing import Any, Dict, Tuple
@@ -25,6 +26,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.distributed.sharding import constrain
+from repro.kernels.moe_gmm.kernel import BLOCK_M
 from repro.kernels.moe_gmm.ops import gmm
 from repro.models import attention as attn_mod
 from repro.models.layers import (
@@ -206,6 +208,95 @@ def route(params, xt: jax.Array, cfg: ModelConfig
     return gate * cfg.routed_scaling_factor, idx
 
 
+# the held experts' window holds their expected rows times this
+WINDOW_HEADROOM = 1.25
+
+
+def window_rows(tokens: int, top_k: int, held: int, n_experts: int
+                ) -> int | None:
+    """Rows of the held experts' window of the sorted assignments: their
+    expected rows, ``tokens * top_k * held / n_experts``, times
+    ``WINDOW_HEADROOM``, rounded up to the kernels' row tile.  None where
+    that is no fewer than the most rows the held experts can take,
+    ``tokens * min(top_k, held)``: every assignment is then computed."""
+    m = round_up(math.ceil(tokens * top_k * held / n_experts
+                           * WINDOW_HEADROOM), BLOCK_M)
+    return m if m < tokens * min(top_k, held) else None
+
+
+def _expert_rows(xt, gate, rows, sizes, off, weights, top_k):
+    """The held experts' SwiGLU over the assignments ``rows`` (indices into
+    the T x top_k assignments, sorted by expert, each expert's rows counted
+    by ``sizes``), each row weighted by its gate and added back to its
+    token.  An index past the assignments reads zero and adds nothing."""
+    wg, wu, wd = weights
+    tok = rows // top_k
+    xs = jnp.take(xt, tok, axis=0, mode="fill", fill_value=0)
+    w = jnp.take(gate, rows, mode="fill", fill_value=0).astype(xt.dtype)
+    # each row weighted before the down product, not after it: its
+    # backward pass then keeps the (rows, F) products it needs anyway, not
+    # the (rows, D) outputs as well
+    h = jax.nn.silu(gmm(xs, wg, sizes, off)) * gmm(xs, wu, sizes, off)
+    ys = gmm(h * w[:, None], wd, sizes, off)  # zero outside the held rows
+    return jnp.zeros_like(xt).at[tok].add(ys, mode="drop")
+
+
+def _window(statics, xt, gate, weights, order, sizes, c):
+    """The held experts' rows ``c * m .. (c + 1) * m``, counted from their
+    first in ``order`` (padded past the assignments by ``m``, so that the
+    slice is never clamped): the held groups, cut to those rows, start at
+    the window's first row, and rows past theirs belong to no group."""
+    off, held, top_k, m = statics
+    edges = jnp.concatenate([jnp.zeros(1, sizes.dtype),
+                             jnp.cumsum(sizes[off:off + held])])
+    cut = jnp.diff(jnp.clip(edges - c * m, 0, m))
+    held_sizes = jnp.zeros_like(sizes).at[off:off + held].set(cut)
+    part = jax.lax.dynamic_slice(order, (sizes[:off].sum() + c * m,), (m,))
+    return _expert_rows(xt, gate, part, held_sizes, off, weights, top_k)
+
+
+def _windows(statics, sizes):
+    """Windows the held experts' rows take; at least one."""
+    off, held, _, m = statics
+    return jnp.maximum((sizes[off:off + held].sum() + m - 1) // m, 1)
+
+
+# The held rows in as many windows as they need, one at the expected load.
+# A vjp of its own, whose backward pass recomputes each window's forward
+# as remat does: autodiff of a loop whose length is known only on the
+# device is not defined.
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _held_rows(statics, xt, gate, weights, order, sizes):
+    def window(c):
+        return _window(statics, xt, gate, weights, order, sizes, c)
+
+    return jax.lax.fori_loop(1, _windows(statics, sizes),
+                             lambda c, y: y + window(c), window(0))
+
+
+def _held_rows_fwd(statics, xt, gate, weights, order, sizes):
+    return (_held_rows(statics, xt, gate, weights, order, sizes),
+            (xt, gate, weights, order, sizes))
+
+
+def _held_rows_bwd(statics, res, dy):
+    xt, gate, weights, order, sizes = res
+
+    def grads(c):
+        def window(xt, gate, weights):
+            return _window(statics, xt, gate, weights, order, sizes, c)
+        return jax.vjp(window, xt, gate, weights)[1](dy)
+
+    def add(c, g):
+        return jax.tree_util.tree_map(jnp.add, g, grads(c))
+
+    g = jax.lax.fori_loop(1, _windows(statics, sizes), add, grads(0))
+    return (*g, None, None)
+
+
+_held_rows.defvjp(_held_rows_fwd, _held_rows_bwd)
+
+
 def moe_ffn_dropless(params, x: jax.Array, cfg: ModelConfig
                      ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """The held experts' share of the layer, plus the shared experts.
@@ -214,9 +305,16 @@ def moe_ffn_dropless(params, x: jax.Array, cfg: ModelConfig
     SwiGLU runs as grouped products over their rows only, and each row,
     weighted, is added back to its token.  What the experts held elsewhere
     would add is left out: on one chip of an expert-parallel deployment
-    that is their chips' part of the exchange.  Counters: ``held_rows``
-    (assignments computed here) and ``load_max`` (the fullest held expert's
-    rows over the held experts' mean)."""
+    that is their chips' part of the exchange.
+
+    The held experts' rows are one run of the sorted order.  Where the
+    shapes allow a window shorter than the most rows they can take
+    (``window_rows``), that run is gathered, computed and scattered in
+    windows of that many rows, as many as the step's rows need (one at
+    the expected load), so no assignment is dropped at any load.
+    Counters: ``held_rows`` (assignments computed here), ``load_max`` (the
+    fullest held expert's rows over the held experts' mean) and ``window``
+    (1 where the held rows fit one window)."""
     b, s, d = x.shape
     t, k = b * s, cfg.top_k
     off, held = cfg.expert_offset, cfg.held_experts
@@ -226,24 +324,24 @@ def moe_ffn_dropless(params, x: jax.Array, cfg: ModelConfig
     flat = idx.reshape(-1)
     order = jnp.argsort(flat)
     sizes = jnp.bincount(flat, length=cfg.n_experts).astype(jnp.int32)
-    tok = order // k
-    xs = jnp.take(xt, tok, axis=0)
-    wg = params["w_gate"].astype(x.dtype)
-    wu = params["w_up"].astype(x.dtype)
-    wd = params["w_down"].astype(x.dtype)
-    w = jnp.take(gate.reshape(-1), order).astype(x.dtype)
-    # each row weighted before the down product, not after it: its
-    # backward pass then keeps the (T * top_k, F) rows it needs anyway, not
-    # the (T * top_k, D) outputs as well
-    h = jax.nn.silu(gmm(xs, wg, sizes, off)) * gmm(xs, wu, sizes, off)
-    ys = gmm(h * w[:, None], wd, sizes, off)  # zero outside the held rows
-    y = jnp.zeros_like(xt).at[tok].add(ys)
+    weights = tuple(params[w].astype(x.dtype)
+                    for w in ("w_gate", "w_up", "w_down"))
+    gate = gate.reshape(-1)
+    rows = sizes[off:off + held].astype(jnp.float32)
+    m = window_rows(t, k, held, cfg.n_experts)
+    if m is None:
+        y = _expert_rows(xt, gate, order, sizes, off, weights, k)
+        window = jnp.float32(0)
+    else:
+        padded = jnp.concatenate([order, jnp.full(m, t * k, order.dtype)])
+        y = _held_rows((off, held, k, m), xt, gate, weights, padded, sizes)
+        window = (rows.sum() <= m).astype(jnp.float32)
 
     if cfg.n_shared_experts:
         y = y + mlp_apply(params["shared"], xt, "swiglu")
-    rows = sizes[off:off + held].astype(jnp.float32)
     aux = {"held_rows": rows.sum(),
-           "load_max": rows.max() / jnp.maximum(rows.mean(), 1.0)}
+           "load_max": rows.max() / jnp.maximum(rows.mean(), 1.0),
+           "window": window}
     return y.reshape(b, s, d), aux
 
 
@@ -329,7 +427,8 @@ class MoELM(DenseLM):
         if cfg.moe_dispatch == "dropless":
             return loss, {"loss": loss, "tokens": denom,
                           "moe_held_rows": aux["held_rows"].sum(),
-                          "moe_load_max": aux["load_max"].max()}
+                          "moe_load_max": aux["load_max"].max(),
+                          "moe_window_share": aux["window"].mean()}
         lb = aux["load_balance"].mean()
         rz = aux["router_z"].mean()
         total = loss + cfg.router_aux_coef * lb + cfg.router_z_coef * rz

@@ -181,8 +181,9 @@ def test_the_shares_add_up_to_the_whole_layer():
 @pytest.mark.parametrize("pull", [10.0, -10.0], ids=["all", "none"])
 def test_no_assignment_is_dropped_at_any_load(pull):
     """A bias that draws every token to the four held experts (each of the
-    128 tokens chooses all four: 512 rows here) or away from them (no row
-    here); the program computes every held row and matches the reference."""
+    128 tokens chooses all four: 512 rows here, past the window) or away
+    from them (no row here, inside it); the program computes every held
+    row and matches the reference."""
     d = _dims()
     x = jax.random.normal(jax.random.PRNGKey(6), (2, 64, 64))
     p = _layer_params(d)
@@ -190,9 +191,83 @@ def test_no_assignment_is_dropped_at_any_load(pull):
     p = dict(p, router_bias=bias)
     y, aux = moe_ffn_dropless(p, x, _program(d).cfg)
     assert float(aux["held_rows"]) == (512.0 if pull > 0 else 0.0)
+    # 512 rows overflow the 256-row window: they take two
+    assert float(aux["window"]) == (0.0 if pull > 0 else 1.0)
     np.testing.assert_allclose(np.asarray(y),
                                np.asarray(ref._experts(x, p, d, "float32")),
                                atol=1e-5, rtol=1e-5)
+
+
+def _layer_grads(p, x, dy, fn):
+    """The layer's output and its gradients for ``x`` and every weight."""
+    def loss(x, p):
+        y = fn(x, p)
+        return jnp.sum(y * dy), y
+    (_, y), grads = jax.value_and_grad(loss, (0, 1), has_aux=True)(x, p)
+    return y, jax.tree_util.tree_leaves(grads)
+
+
+def _leaf_gap(a, b):
+    nb = float(jnp.linalg.norm(b))
+    return float(jnp.linalg.norm(a - b)) / nb if nb else float(
+        jnp.linalg.norm(a))
+
+
+@pytest.mark.parametrize("offset,pull,fits", [(4, 0.0, True),
+                                              (12, 0.0, True),
+                                              (4, 10.0, False)],
+                         ids=["middle", "last", "two-windows"])
+def test_the_window_matches_every_row_and_the_reference(offset, pull, fits,
+                                                        monkeypatch):
+    """At a load that fits the held experts' window (128 tokens x 6 over
+    16 experts, 4 held: 192 rows expected, a window of 256), the layer and
+    its gradients match the path over every assignment and the float32
+    reference.  Held last, the window runs past the sorted assignments
+    into the padding.  A bias that draws every token to the held experts
+    (512 rows) takes two windows, and matches as well."""
+    d = _dims(expert_offset=offset)
+    cfg = _program(d).cfg
+    assert moe.window_rows(128, 6, 4, 16) == 256
+    x = jax.random.normal(jax.random.PRNGKey(7), (2, 64, 64))
+    dy = jax.random.normal(jax.random.PRNGKey(8), x.shape)
+    p = _layer_params(d)
+    p = dict(p, router_bias=jnp.zeros(16).at[offset:offset + 4].set(pull))
+    _, aux = moe_ffn_dropless(p, x, cfg)
+    assert float(aux["window"]) == float(fits)
+    assert (0 < float(aux["held_rows"]) <= 256) == fits
+
+    y, grads = _layer_grads(
+        p, x, dy, lambda x, p: moe_ffn_dropless(p, x, cfg)[0])
+    want_y, want = _layer_grads(
+        p, x, dy, lambda x, p: ref._experts(x, p, d, "float32"))
+    with monkeypatch.context() as m:
+        m.setattr(moe, "window_rows", lambda *shape: None)
+        full_y, full = _layer_grads(
+            p, x, dy, lambda x, p: moe_ffn_dropless(p, x, cfg)[0])
+    np.testing.assert_allclose(np.asarray(y), np.asarray(full_y),
+                               atol=1e-6, rtol=1e-6)
+    np.testing.assert_allclose(np.asarray(y), np.asarray(want_y),
+                               atol=1e-5, rtol=1e-5)
+    assert len(grads) == len(full) == len(want) == 1 + len(
+        jax.tree_util.tree_leaves(p))
+    for a, b, c in zip(grads, full, want):
+        assert _leaf_gap(a, b) <= 1e-6
+        assert _leaf_gap(a, c) <= GRAD_TOL
+
+
+def test_the_window_exists_only_below_the_most_rows_held(small):
+    """The window is the expected held rows with a quarter of room, in
+    whole row tiles, and only where that is short of every assignment
+    the held experts could take; a step of the model counts the MoE
+    layers that took it."""
+    # Moonlight's one-chip share: 8192 tokens x 6, 8 of 64 experts held
+    assert moe.window_rows(8192, 6, 8, 64) == 7680
+    # every expert held, or a load no larger than one tile: no window
+    assert moe.window_rows(8192, 6, 64, 64) is None
+    assert moe.window_rows(8, 6, 8, 64) is None
+    d, params, batch = small
+    _, metrics = _program(d).loss(params, batch)
+    assert float(metrics["moe_window_share"]) == 1.0
 
 
 def test_latent_attention_refuses_to_serve(small):

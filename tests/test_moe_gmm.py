@@ -69,6 +69,40 @@ def test_tgmm_kernel_matches_jnp(sizes, start, n_local):
                                    rtol=TOL)
 
 
+# a window of the held groups: the sizes of the groups outside them zeroed,
+# so the held rows start at row 0, and rows past them padding or the rows
+# of later groups; (sizes of the held groups, first held group, groups)
+WINDOWS = [
+    ([170, 3, 0, 129], 2, 4),      # the last held group ends mid-tile
+    ([128, 0, 128], 5, 3),         # tile-aligned, an empty group between
+    ([0, 0, 0], 1, 3),             # nothing routed here
+]
+
+
+@pytest.mark.parametrize("held,start,n_local", WINDOWS)
+def test_kernels_on_a_window_of_the_held_groups(held, start, n_local):
+    sizes = [0] * start + held + [0] * 2
+    m = 512
+    s, _, w, _ = _case(sizes, 256, 384, n_local)
+    r = jax.random.split(jax.random.PRNGKey(1), 2)
+    x = jax.random.normal(r[0], (m, 256))
+    dy = jax.random.normal(r[1], (m, 384))
+    n = sum(held)
+    want = gmm_reference(x, w, s, start)
+    got = K.gmm(x, w, s, jnp.int32(start), tm=128, tk=128, tn=128,
+                interpret=True)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=TOL,
+                               rtol=TOL)
+    assert not np.asarray(got)[n:].any()
+    got_w = K.tgmm(x, dy, s, jnp.int32(start), n_local, tm=128, tk=128,
+                   tn=128, interpret=True)
+    for j in range(n_local):
+        rows = _rows_of(s, start + j)
+        want_w = np.asarray(x)[rows].T @ np.asarray(dy)[rows]
+        np.testing.assert_allclose(np.asarray(got_w[j]), want_w, atol=TOL,
+                                   rtol=TOL)
+
+
 def test_gmm_gradients_match_jnp():
     """The wrapper's own tiles and row padding, and its backward pass
     (``moe_gmm`` for the rows, ``moe_tgmm`` for the weights)."""

@@ -143,6 +143,30 @@ def test_moe_gmm_compiles_and_carries_stable_names(one_chip, k, n):
     assert names == ["moe_gmm", "moe_gmm", "moe_tgmm"]
 
 
+# one such MoE layer whole, forward and backward: the held experts' rows
+# are dispatched in windows of 7,680 (the expected 6,144 with room), and
+# no array over all 49,152 assignment rows is built
+def test_moe_layer_dispatches_a_window_of_rows(one_chip, monkeypatch):
+    import repro.kernels
+    from repro.configs.moonshot_v1_16b_a3b import MODEL
+    from repro.models.layers import ParamDef
+    from repro.models.moe import moe_ffn_def, moe_ffn_dropless
+
+    monkeypatch.setattr(repro.kernels, "on_tpu", lambda: True)
+    cfg = MODEL.replace(moe_dispatch="dropless", n_held_experts=8)
+    params = jax.tree_util.tree_map(
+        lambda p: _sds(one_chip, p.shape), moe_ffn_def(cfg),
+        is_leaf=lambda p: isinstance(p, ParamDef))
+
+    def loss(params, x):
+        return moe_ffn_dropless(params, x, cfg)[0].sum()
+
+    text = _compile_for_chip(jax.grad(loss, argnums=(0, 1)), params,
+                             _sds(one_chip, (1, 8192, 2048))).as_text()
+    assert "f32[7680,2048]" in text and "f32[7680,1408]" in text
+    assert not re.search(r"\[49152,\d+", text)
+
+
 def test_flash_decode_compiles(one_chip):
     q = _sds(one_chip, (8, 12, 64))
     cache = _sds(one_chip, (8, 2048, 12, 64))
