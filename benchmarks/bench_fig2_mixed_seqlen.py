@@ -22,7 +22,7 @@ def run(quick: bool = False) -> List[Row]:
     # short-only: constant seqlen = SEQ/8 via a "two_stage" that never switches
     tc_short = bench_config(slw=True, lr=lr, steps=steps, pacing="two_stage")
     tc_short = dataclasses.replace(
-        tc_short, slw=SLWConfig(enabled=True, pacing="two_stage",
+        tc_short, slw=SLWConfig(pacing="two_stage",
                                 two_stage_short_len=SEQ // 8,
                                 two_stage_switch_step=10 * steps,
                                 duration_steps=10 * steps,

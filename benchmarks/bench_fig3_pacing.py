@@ -57,7 +57,7 @@ def run(quick: bool = False) -> List[Row]:
     from repro.core.regulators import predict_trajectory
     tc_tuned = dataclasses.replace(
         bench_config(slw=True, lr=lr, steps=steps, warmup_steps=warmup),
-        slw=SLWConfig(enabled=True, start_seq_len=tuned.seqlen_s,
+        slw=SLWConfig(start_seq_len=tuned.seqlen_s,
                       duration_steps=tuned.duration, round_multiple=8,
                       max_buckets=12))
     plans = predict_trajectory(tc_tuned, tuned.duration)

@@ -29,7 +29,6 @@ import numpy as np
 
 from benchmarks.common import Row, bench_config, run_arm, stability_row
 from repro.configs.base import OptimizerConfig, RegulatorSpec
-from repro.core.regulators import auto_specs
 from repro.optim import (adamw_update, apply_updates, build_optimizer,
                          clip_by_global_norm, init_opt_state)
 
@@ -104,7 +103,7 @@ def _shampoo_staleness_row(steps: int = 12, interval: int = 5) -> Row:
 
 def _with_throttle(tc):
     return dataclasses.replace(
-        tc, regulators=auto_specs(tc)
+        tc, regulators=tc.regulators
         + (RegulatorSpec(kind="var_lr_throttle"),))
 
 

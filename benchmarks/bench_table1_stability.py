@@ -14,7 +14,6 @@ from typing import List
 
 from benchmarks.common import (Row, bench_config, run_arm, stability_row)
 from repro.configs.base import RegulatorSpec
-from repro.core.regulators import auto_specs
 
 MODERATE_LR = 6e-3
 # Calibrated on this container: fp32 + tiny params + global clip suppress
@@ -26,7 +25,7 @@ AGGRESSIVE_LR = 0.5
 def _with_throttle(tc):
     import dataclasses
     return dataclasses.replace(
-        tc, regulators=auto_specs(tc)
+        tc, regulators=tc.regulators
         + (RegulatorSpec(kind="var_lr_throttle"),))
 
 

@@ -77,7 +77,9 @@ def run(quick: bool = False) -> List[Row]:
     total_tokens = 157e9
     slw_cfg = SLWConfig(start_seq_len=64, duration_steps=45_000,
                         round_multiple=8, max_buckets=64)
-    ladder = pacing.bucket_ladder(slw_cfg, full)
+    ladder = pacing.bucket_ladder(slw_cfg.start_seq_len, full,
+                                  slw_cfg.round_multiple,
+                                  slw_cfg.max_buckets)
     t_full = _step_time_model(cfg, batch, full)
 
     # integrate the SLW schedule to the same token budget

@@ -41,11 +41,15 @@ def bench_config(slw: bool = False, lr: float = 1e-3, steps: int = 150,
                  seq: int = SEQ, batch: int = BATCH, grad_clip: float = 1.0,
                  mode: str = "truncate", seed: int = 1234,
                  total_tokens: int = 0,
-                 regulators: Tuple[RegulatorSpec, ...] = ()) -> TrainConfig:
-    """One bench arm.  `slw` and `batch_warmup` now *compose* through the
-    regulator stack (the paper's joint recipe is both at once); `regulators`
-    overrides the auto-derived stack entirely (e.g. to add the adaptive
-    beyond-paper regulators)."""
+                 regulators: Optional[Tuple[RegulatorSpec, ...]] = None
+                 ) -> TrainConfig:
+    """One bench arm.  `slw` and `batch_warmup` turn on their regulators
+    and *compose* (the paper's joint recipe is both at once), before the LR
+    schedule; `regulators` gives the whole stack instead (e.g. to add the
+    adaptive beyond-paper regulators)."""
+    if regulators is None:
+        kinds = ("seqlen",) * slw + ("batch_warmup",) * batch_warmup + ("lr",)
+        regulators = tuple(RegulatorSpec(k) for k in kinds)
     return TrainConfig(
         model=BENCH_MODEL,
         optimizer=OptimizerConfig(
@@ -55,11 +59,11 @@ def bench_config(slw: bool = False, lr: float = 1e-3, steps: int = 150,
             total_steps=steps,
             total_tokens=total_tokens or steps * batch * seq,
             grad_clip=grad_clip),
-        slw=SLWConfig(enabled=slw, pacing=pacing, start_seq_len=start_seq,
+        slw=SLWConfig(pacing=pacing, start_seq_len=start_seq,
                       duration_steps=duration or steps // 3,
                       round_multiple=8, max_buckets=12, mode=mode),
         batch_warmup=BatchWarmupConfig(
-            enabled=batch_warmup, start_batch=max(batch // 4, 1),
+            start_batch=max(batch // 4, 1),
             warmup_tokens=(duration or steps // 3) * batch * seq // 2),
         regulators=regulators,
         seq_len=seq, global_batch=batch, seed=seed, remat="none",

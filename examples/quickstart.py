@@ -15,8 +15,8 @@ import sys
 sys.path.insert(0, "src")
 
 from repro.configs import get_arch, reduced
-from repro.configs.base import (BatchWarmupConfig, OptimizerConfig, SLWConfig,
-                                TrainConfig)
+from repro.configs.base import (BatchWarmupConfig, OptimizerConfig,
+                                RegulatorSpec, SLWConfig, TrainConfig)
 from repro.launch.train import train
 
 
@@ -43,13 +43,16 @@ def main():
             lr=6e-3, min_lr=2e-4, schedule="token_cosine",
             warmup_steps=15, warmup_tokens=15 * batch * seq,
             total_steps=steps, total_tokens=steps * batch * seq),
-        slw=SLWConfig(enabled=True, pacing="linear", start_seq_len=8,
+        slw=SLWConfig(pacing="linear", start_seq_len=8,
                       duration_steps=steps // 3, round_multiple=8,
                       max_buckets=12),
-        # composes with SLW through the regulator stack (the paper's
-        # joint recipe: short sequences make the warming batch/LR safe)
-        batch_warmup=BatchWarmupConfig(enabled=True, start_batch=batch // 2,
+        batch_warmup=BatchWarmupConfig(start_batch=batch // 2,
                                        warmup_tokens=steps * batch * seq // 8),
+        # batch warmup composes with SLW through the regulator stack (the
+        # paper's joint recipe: short sequences make the warming batch/LR
+        # safe)
+        regulators=(RegulatorSpec("seqlen"), RegulatorSpec("batch_warmup"),
+                    RegulatorSpec("lr")),
         seq_len=seq, global_batch=batch, remat="none", eval_interval=20)
 
     res = train(tc, quiet=False)

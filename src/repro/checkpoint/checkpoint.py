@@ -18,7 +18,7 @@ Design goals (the fault-tolerance contract):
   automatic fallback: the corrupt directory is quarantined (renamed
   ``corrupt.step_*`` so no future restart trusts it, but the payload stays
   on disk for postmortems) and the previous ``step_*`` directory is tried.
-* **host state included** — curriculum state, loss-ratio tracker, data
+* **host state included** — regulator state, loss-ratio tracker, data
   cursor, token counters ride along in the manifest's ``host`` dict, so a
   restart resumes the SLW schedule exactly.
 * **fault-injectable** — the two rename-boundary crash points call into
@@ -212,7 +212,9 @@ def migrate_host_state(host: Dict) -> Dict:
     Pre-regulator checkpoints carried per-object payloads
     (``{"curriculum": ..., "tracker": ...}``); the control plane now
     checkpoints one ``controller`` dict (see core.regulators.ControllerState).
-    Legacy curriculum state maps onto the ``seqlen`` regulator's slot.
+    Legacy curriculum state maps onto the ``seqlen`` regulator's slot, and
+    its step becomes the controller's when the dict has no ``step`` of its
+    own.
     A host dict carrying a legacy monolithic ``{"m","v","count"}`` opt
     state (in-memory snapshots, ring payloads) is upgraded into the chain
     format (``{"adam": {...}, ...}``); the on-disk equivalent happens
@@ -231,7 +233,9 @@ def migrate_host_state(host: Dict) -> Dict:
     if "curriculum" in host:
         regs["seqlen"] = host["curriculum"]
     out["controller"] = {
-        "step": host.get("step", 0),
+        # the curriculum kept its own step; it is the run's step when the
+        # dict has none beside it
+        "step": host.get("step", host.get("curriculum", {}).get("step", 0)),
         "tokens_seen": host.get("tokens_seen", 0),
         "regulators": regs,
         "tracker": host.get("tracker", {}),

@@ -170,11 +170,12 @@ LM_SHAPES: Tuple[ShapeConfig, ...] = (
 
 @dataclass(frozen=True)
 class SLWConfig:
-    """Sequence Length Warmup — the paper's contribution (Section 4)."""
+    """Sequence Length Warmup — the paper's contribution (Section 4): the
+    parameters of the ``seqlen`` regulator, which runs only when
+    ``TrainConfig.regulators`` lists it."""
 
-    enabled: bool = True
     # pacing: linear (paper default) | root | two_stage (Shortformer baseline)
-    #         | variance_gated (beyond-paper) | constant
+    #         | variance_gated (beyond-paper)
     pacing: str = "linear"
     start_seq_len: int = 8  # seqlen_s
     end_seq_len: int = 0  # seqlen_e; 0 -> full shape seq_len
@@ -197,9 +198,10 @@ class SLWConfig:
 
 @dataclass(frozen=True)
 class BatchWarmupConfig:
-    """GPT-3 style batch-size warmup (baseline the paper compares against)."""
+    """GPT-3 style batch-size warmup (baseline the paper compares against):
+    the parameters of the ``batch_warmup`` regulator, which runs only when
+    ``TrainConfig.regulators`` lists it."""
 
-    enabled: bool = False
     start_batch: int = 16
     warmup_tokens: int = 4_000_000_000
 
@@ -208,36 +210,27 @@ class BatchWarmupConfig:
 class RegulatorSpec:
     """One entry in ``TrainConfig.regulators`` — the composable control plane.
 
-    ``kind`` selects the regulator; the remaining fields parameterize the
-    kinds that have no legacy config of their own.  Kinds with a legacy
-    config (``seqlen`` <- SLWConfig, ``batch_warmup`` <- BatchWarmupConfig,
-    ``lr`` <- OptimizerConfig) read their parameters from those configs, so
-    one spec entry is just an opt-in switch for them.
+    ``kind`` selects the regulator; the remaining fields parameterize
+    ``var_lr_throttle``.  The other kinds read their parameters from their
+    own records (``seqlen`` <- SLWConfig, ``batch_warmup`` <-
+    BatchWarmupConfig, ``lr`` <- OptimizerConfig, ``critical_batch`` <-
+    GNSConfig), so for them one spec entry is the switch that turns them on.
 
     Kinds:
       seqlen            — SLW curriculum (pacing + variance gate), SLWConfig
       batch_warmup      — GPT-3-style linear batch warmup, BatchWarmupConfig
       lr                — token-/step-wise LR schedule, OptimizerConfig
-      grad_noise_batch  — adaptive batch sizing from the relative std of the
-                          gradient norm (Lau et al.-style telemetry-driven
-                          batch schedule)
       var_lr_throttle   — multiplicative LR/grad-clip backoff while the Adam
                           variance max spikes above its trailing mean
                           (Kosson et al.-style warmup-free LR control)
       critical_batch    — B_noise-measured batch warmup (repro.gns): grow
                           the batch while the measured gradient noise scale
                           exceeds ``TrainConfig.gns.headroom`` x the current
-                          batch, hold otherwise.  Supersedes the
-                          grad_noise_batch grad-norm-EMA proxy; reads its
-                          parameters from ``TrainConfig.gns``.
+                          batch, hold otherwise; reads its parameters
+                          from ``TrainConfig.gns``.
     """
 
     kind: str
-    # grad_noise_batch
-    min_batch: int = 0  # 0 -> full_batch // 8
-    noise_window: int = 16  # EMA horizon (steps) for grad-norm stats
-    noise_target: float = 0.25  # grow batch while rel. grad-norm std exceeds
-    growth: float = 1.5  # multiplicative batch growth per trigger
     # var_lr_throttle
     gate: float = 2.0  # throttle when var_max > gate * trailing mean
     floor: float = 0.1  # never scale LR below floor * scheduled
@@ -344,12 +337,13 @@ class TrainConfig:
     optimizer: OptimizerConfig = field(default_factory=OptimizerConfig)
     slw: SLWConfig = field(default_factory=SLWConfig)
     batch_warmup: BatchWarmupConfig = field(default_factory=BatchWarmupConfig)
-    # Composable control plane (core.regulators).  Empty tuple = derive from
-    # the legacy configs above: seqlen if slw.enabled, batch_warmup if
-    # batch_warmup.enabled, and always the LR schedule — so the paper's
-    # *joint* recipe (SLW + 8x batch + 4x/40x LR warmup) is just "enable
-    # both".  A non-empty tuple overrides the derivation entirely.
-    regulators: Tuple[RegulatorSpec, ...] = ()
+    # Composable control plane (core.regulators), in stack order.  The
+    # tuple is the one switch: SLW runs iff it lists "seqlen", batch warmup
+    # iff it lists "batch_warmup" (``slw`` and ``batch_warmup`` above only
+    # parameterize them).  The paper's *joint* recipe is
+    # (seqlen, batch_warmup, lr).
+    regulators: Tuple[RegulatorSpec, ...] = (RegulatorSpec("seqlen"),
+                                             RegulatorSpec("lr"))
     # gradient-noise-scale measurement + pre-spike forecasting (repro.gns);
     # disabled by default — the train step's trace is untouched unless on
     gns: GNSConfig = field(default_factory=GNSConfig)

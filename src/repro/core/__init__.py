@@ -1,14 +1,8 @@
 """The paper's contribution: Sequence Length Warmup + its instrumentation."""
-from repro.core.batch_warmup import BatchWarmup  # noqa: F401
-from repro.core.curriculum import (  # noqa: F401
-    CurriculumState,
-    SLWCurriculum,
-    apply_seqlen,
-)
+from repro.core.curriculum import apply_seqlen  # noqa: F401
 from repro.core.regulators import (  # noqa: F401
     BatchSizeRegulator,
     ControllerState,
-    GradNoiseBatchRegulator,
     LRScheduleRegulator,
     Regulator,
     RegulatorStack,
@@ -16,7 +10,6 @@ from repro.core.regulators import (  # noqa: F401
     StepPlan,
     StepTelemetry,
     VarianceLRThrottle,
-    auto_specs,
     build_stack,
     predict_trajectory,
 )

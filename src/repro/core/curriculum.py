@@ -1,7 +1,6 @@
-"""SLW curriculum controller: the host-side state machine that drives the
-per-step sequence length, applies it to batches, and does token accounting.
+"""SLW's batch transform: apply a step's sequence length to a host batch.
 
-Two batch transforms:
+Two modes:
 
 * ``truncate`` — paper-faithful (§4): keep the first ``s_t`` tokens of each
   pre-indexed full-length sequence; the rest of the step's tokens are dropped
@@ -14,41 +13,24 @@ Two batch transforms:
 
 All slicing happens host-side on numpy arrays *before* device transfer, so a
 warmup step moves only ``B * s_t`` tokens over PCIe/ICI, not the full batch.
-
-The controller's state (step, tokens_seen, variance-gate level) is part of
-the training checkpoint: a restart mid-warmup resumes the curriculum exactly
-(re-running long sequences early after a crash would reintroduce the very
-instability SLW removes).
+The schedule that picks ``s_t`` is ``core.regulators.SeqLenRegulator``.
 """
 from __future__ import annotations
 
-import dataclasses
-from dataclasses import dataclass, field
-from typing import Dict, Optional, Tuple
+from typing import Dict, Tuple
 
 import numpy as np
-
-from repro.configs.base import SLWConfig
-from repro.core import pacing
-
-
-@dataclass
-class CurriculumState:
-    step: int = 0
-    tokens_seen: int = 0
-    gate_level: int = 0  # index into the bucket ladder (variance_gated)
-    var_trailing: float = 0.0  # trailing mean of Adam variance-max
 
 
 def apply_seqlen(batch: Dict[str, np.ndarray], s_t: int,
                  mode: str = "truncate") -> Tuple[Dict[str, np.ndarray], int]:
     """Apply sequence length ``s_t`` to a host-side batch.
 
-    Standalone so the trainer can execute a ``StepPlan`` without owning a
-    curriculum object.  Sequence-axis keys are truncated/repacked; a
-    vision-patch prefix (``patch_embeds``) is passed through untouched (SLW
-    warms up only the text segment).  Returns (batch, tokens_this_step),
-    prefix tokens included in the count.
+    What ``RegulatorStack.apply`` runs for a ``StepPlan``.  Sequence-axis
+    keys are truncated/repacked; a vision-patch prefix (``patch_embeds``)
+    is passed through untouched (SLW warms up only the text segment).
+    Returns (batch, tokens_this_step), prefix tokens included in the
+    count.
     """
     seq_keys = [k for k in ("tokens", "labels", "loss_mask", "frames")
                 if k in batch]
@@ -73,68 +55,3 @@ def apply_seqlen(batch: Dict[str, np.ndarray], s_t: int,
         tokens += int(out["patch_embeds"].shape[0]
                       * out["patch_embeds"].shape[1])
     return out, tokens
-
-
-class SLWCurriculum:
-    def __init__(self, cfg: SLWConfig, full_seq: int, warmup_steps_hint: int = 0,
-                 prefix_tokens: int = 0):
-        self.cfg = cfg
-        self.full_seq = full_seq
-        self.warmup_steps_hint = warmup_steps_hint
-        self.prefix_tokens = prefix_tokens  # vlm: frozen image-patch prefix
-        self.ladder = pacing.bucket_ladder(cfg, full_seq - prefix_tokens)
-        self.state = CurriculumState()
-
-    # -- schedule -----------------------------------------------------------
-    def seqlen_for_step(self, step: Optional[int] = None) -> int:
-        step = self.state.step if step is None else step
-        if self.cfg.enabled and self.cfg.pacing == "variance_gated":
-            envelope = pacing.seqlen_at(
-                self.cfg, step, self.full_seq - self.prefix_tokens,
-                self.warmup_steps_hint, self.ladder)
-            gated = self.ladder[min(self.state.gate_level,
-                                    len(self.ladder) - 1)]
-            return min(envelope, gated) if step else min(
-                envelope, self.ladder[0])
-        return pacing.seqlen_at(self.cfg, step,
-                                self.full_seq - self.prefix_tokens,
-                                self.warmup_steps_hint, self.ladder)
-
-    def observe(self, var_max: float) -> None:
-        """variance_gated pacing: advance the ladder only while the Adam
-        variance max element stays below gate * trailing mean (beyond-paper;
-        closes the loop on the paper's §3 correlation)."""
-        st = self.state
-        if st.var_trailing == 0.0:
-            st.var_trailing = var_max
-        ok = var_max <= self.cfg.variance_gate * st.var_trailing
-        st.var_trailing = 0.9 * st.var_trailing + 0.1 * var_max
-        if ok and st.gate_level < len(self.ladder) - 1:
-            st.gate_level += 1
-
-    # -- batch transform ------------------------------------------------------
-    def apply(self, batch: Dict[str, np.ndarray], seqlen: Optional[int] = None
-              ) -> Tuple[Dict[str, np.ndarray], int]:
-        """Apply the current sequence length. Returns (batch, tokens_this_step).
-
-        Sequence-axis keys are truncated/repacked; the vision-patch prefix is
-        passed through untouched (SLW warms up only the text segment).
-        """
-        s_t = self.seqlen_for_step() if seqlen is None else seqlen
-        return apply_seqlen(batch, s_t, self.cfg.mode)
-
-    # -- accounting -----------------------------------------------------------
-    def step_complete(self, tokens_this_step: int) -> None:
-        self.state.step += 1
-        self.state.tokens_seen += tokens_this_step
-
-    @property
-    def at_full_length(self) -> bool:
-        return self.seqlen_for_step() >= self.full_seq - self.prefix_tokens
-
-    # -- checkpointing ---------------------------------------------------------
-    def state_dict(self) -> Dict:
-        return dataclasses.asdict(self.state)
-
-    def load_state_dict(self, d: Dict) -> None:
-        self.state = CurriculumState(**d)

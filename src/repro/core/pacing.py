@@ -6,8 +6,8 @@ The paper's pacing function is step-wise linear:
 
 with a post-processing ``seqlen_t -= seqlen_t mod 8`` for V100 tensor cores.
 Also implemented: the root variant (paper §4 item ii), the Shortformer
-discrete 2-stage schedule (the baseline §5.1 shows diverging at the switch),
-and a constant schedule.
+discrete 2-stage schedule (the baseline §5.1 shows diverging at the switch)
+and the linear envelope of the beyond-paper variance gate.
 
 TPU adaptation: every distinct sequence length is an XLA recompilation, so
 the raw pacing value is quantized onto a bounded *bucket ladder* —
@@ -32,30 +32,24 @@ def raw_seqlen(cfg: SLWConfig, step: int, full_len: int,
     s0 = cfg.start_seq_len
     s1 = cfg.end_seq_len or full_len
     T = cfg.duration_steps or max(2 * warmup_steps_hint, 1)
-    if not cfg.enabled or cfg.pacing == "constant":
-        return float(s1)
-    if cfg.pacing == "linear":
+    if cfg.pacing in ("linear", "variance_gated"):
+        # variance_gated (beyond-paper) is driven by the observed Adam
+        # variance max; the seqlen regulator owns the gate and reads only
+        # this linear upper envelope here
         return s0 + (s1 - s0) * min(step / T, 1.0)
     if cfg.pacing == "root":
         return s0 + (s1 - s0) * min((step / T) ** (1.0 / cfg.root_degree), 1.0)
     if cfg.pacing == "two_stage":  # Shortformer baseline
         switch = cfg.two_stage_switch_step or T
         return float(cfg.two_stage_short_len if step < switch else s1)
-    if cfg.pacing == "variance_gated":
-        # beyond-paper: driven by observed Adam variance-max; the curriculum
-        # controller owns the gate state and calls `raw_seqlen` only for the
-        # linear upper envelope.
-        return s0 + (s1 - s0) * min(step / T, 1.0)
     raise ValueError(f"unknown pacing {cfg.pacing!r}")
 
 
-def bucket_ladder(cfg: SLWConfig, full_len: int) -> Tuple[int, ...]:
-    """Monotone ladder of allowed sequence lengths, |ladder| <= max_buckets."""
-    s0 = cfg.start_seq_len
-    s1 = cfg.end_seq_len or full_len
-    m = cfg.round_multiple
-    if not cfg.enabled:
-        return (s1,)
+def bucket_ladder(s0: int, s1: int, m: int,
+                  max_buckets: int) -> Tuple[int, ...]:
+    """Monotone ladder of allowed sequence lengths from ``s0`` up to the
+    full length ``s1`` in steps of the rounding multiple ``m``,
+    |ladder| <= max_buckets."""
     ladder: List[int] = []
     # geometric sub-multiple region
     v = s0
@@ -65,7 +59,7 @@ def bucket_ladder(cfg: SLWConfig, full_len: int) -> Tuple[int, ...]:
     # arithmetic multiples of m
     lo = max(m, s0 - s0 % m or m)
     n_arith = max(1, (s1 - lo) // m + 1)
-    budget = max(1, cfg.max_buckets - len(ladder))
+    budget = max(1, max_buckets - len(ladder))
     stride = max(1, math.ceil(n_arith / budget))
     v = lo
     while v < s1:
@@ -93,5 +87,6 @@ def seqlen_at(cfg: SLWConfig, step: int, full_len: int,
               warmup_steps_hint: int = 0,
               ladder: Sequence[int] = None) -> int:
     if ladder is None:
-        ladder = bucket_ladder(cfg, full_len)
+        ladder = bucket_ladder(cfg.start_seq_len, cfg.end_seq_len or full_len,
+                               cfg.round_multiple, cfg.max_buckets)
     return quantize(raw_seqlen(cfg, step, full_len, warmup_steps_hint), ladder)

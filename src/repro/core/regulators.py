@@ -23,15 +23,18 @@ their state round-trips through one :class:`ControllerState`, which is the
 single host-state payload the checkpoint carries — a restart mid-warmup
 resumes every schedule exactly.
 
+Every regulator plans from the stack's counters in the telemetry
+(``tele.step`` / ``tele.tokens_seen``); none keeps a step counter of its
+own.  Which regulators run is ``TrainConfig.regulators`` and nothing else.
+
 Beyond-paper clients of the same protocol (see PAPERS.md):
 
-* :class:`GradNoiseBatchRegulator` — telemetry-driven batch sizing in the
-  spirit of Lau et al., *Adaptive Batch Size Schedules for Distributed
-  Training of Language Models*: grow the batch only while the relative
-  std of the gradient norm says averaging would help.
 * :class:`VarianceLRThrottle` — Kosson et al.-style warmup-free LR
   control: multiplicatively back off the LR (and grad clip) while the
   Adam variance max spikes above its trailing mean, recover when calm.
+* ``critical_batch`` (``repro.gns.regulator``) — batch warmup driven by
+  the measured gradient noise scale (Lau et al., *Adaptive Batch Size
+  Schedules for Distributed Training of Language Models*).
 """
 from __future__ import annotations
 
@@ -44,8 +47,8 @@ import numpy as np
 
 from repro.configs.base import (BatchWarmupConfig, OptimizerConfig,
                                 RegulatorSpec, SLWConfig, TrainConfig)
-from repro.core.batch_warmup import BatchWarmup, quantize_batch
-from repro.core.curriculum import SLWCurriculum, apply_seqlen
+from repro.core import pacing
+from repro.core.curriculum import apply_seqlen
 from repro.optim.schedule import lr_at
 
 
@@ -104,9 +107,9 @@ class StepPlan:
 
 @dataclass
 class ControllerState:
-    """Unified checkpointable state of the whole control plane (replaces the
-    per-object ``state_dict`` plumbing: curriculum + tracker + ad-hoc
-    counters each riding the checkpoint separately)."""
+    """Unified checkpointable state of the whole control plane: the one
+    step/token counter pair, each regulator's slice and the loss-ratio
+    tracker."""
 
     step: int = 0
     tokens_seen: int = 0
@@ -149,53 +152,101 @@ class Regulator:
         pass
 
 
+def quantize_batch(raw: float, dp_size: int, min_batch: int,
+                   full_batch: int) -> int:
+    """Round down to a multiple of the data-parallel size and clip to
+    [max(min_batch, dp_size), full_batch] — the paper's §5.1 structural
+    constraint, shared by every batch-sizing regulator."""
+    b = int(raw) - int(raw) % dp_size
+    return int(np.clip(b, max(min_batch, dp_size), full_batch))
+
+
 class SeqLenRegulator(Regulator):
-    """SLW curriculum (paper §4) on the protocol: pacing function + bucket
-    ladder + the variance gate, state-carried by the wrapped curriculum."""
+    """SLW (paper §4) on the protocol: the pacing function at ``tele.step``,
+    quantized down onto the bucket ladder, and for ``variance_gated``
+    pacing (beyond-paper) the gate that holds it at or below the rung the
+    observed Adam variance max has allowed.  Its state is the gate alone,
+    so a restart mid-warmup resumes the schedule exactly (re-running long
+    sequences early after a crash would reintroduce the very instability
+    SLW removes)."""
 
     name = "seqlen"
 
     def __init__(self, cfg: SLWConfig, full_seq: int,
                  warmup_steps_hint: int = 0, prefix_tokens: int = 0):
         self.cfg = cfg
-        self.curriculum = SLWCurriculum(
-            cfg, full_seq, warmup_steps_hint=warmup_steps_hint,
-            prefix_tokens=prefix_tokens)
+        # SLW warms up the text only: a vlm's image-patch prefix is frozen
+        self.text_len = full_seq - prefix_tokens
+        self.warmup_steps_hint = warmup_steps_hint
+        self.ladder = pacing.bucket_ladder(
+            cfg.start_seq_len, cfg.end_seq_len or self.text_len,
+            cfg.round_multiple, cfg.max_buckets)
+        self.gate_level = 0  # index into the ladder (variance_gated)
+        self.var_trailing = 0.0  # trailing mean of the Adam variance max
 
     @property
     def mode(self) -> str:
         return self.cfg.mode
 
     def plan(self, tele: StepTelemetry, plan: StepPlan) -> StepPlan:
-        plan.seq_len = min(plan.seq_len, self.curriculum.seqlen_for_step())
+        s = pacing.seqlen_at(self.cfg, tele.step, self.text_len,
+                             self.warmup_steps_hint, self.ladder)
+        if self.cfg.pacing == "variance_gated":
+            # step 0 runs the smallest bucket: nothing has been observed
+            rung = min(self.gate_level, len(self.ladder) - 1) \
+                if tele.step else 0
+            s = min(s, self.ladder[rung])
+        plan.seq_len = min(plan.seq_len, s)
         return plan
 
     def observe(self, tele: StepTelemetry, tokens_step: int) -> None:
-        if self.cfg.pacing == "variance_gated" and math.isfinite(tele.var_max):
-            self.curriculum.observe(tele.var_max)
-        self.curriculum.step_complete(tokens_step)
+        """variance_gated: climb one rung only while the Adam variance max
+        stays below gate x its trailing mean (closes the loop on the
+        paper's §3 correlation)."""
+        v = tele.var_max
+        if self.cfg.pacing != "variance_gated" or not math.isfinite(v):
+            return
+        if self.var_trailing == 0.0:
+            self.var_trailing = v
+        ok = v <= self.cfg.variance_gate * self.var_trailing
+        self.var_trailing = 0.9 * self.var_trailing + 0.1 * v
+        if ok and self.gate_level < len(self.ladder) - 1:
+            self.gate_level += 1
 
     def state_dict(self) -> Dict[str, Any]:
-        return self.curriculum.state_dict()
+        return {"gate_level": self.gate_level,
+                "var_trailing": self.var_trailing}
 
     def load_state_dict(self, d: Dict[str, Any]) -> None:
-        self.curriculum.load_state_dict(d)
+        # slices written before the regulator planned from the stack's
+        # counters also carry "step" and "tokens_seen"; the stack's own
+        # (ControllerState) are the ones that count
+        self.gate_level = int(d.get("gate_level", 0))
+        self.var_trailing = float(d.get("var_trailing", 0.0))
 
 
 class BatchSizeRegulator(Regulator):
-    """GPT-3-style linear batch warmup (paper §5.1 baseline), quantized to
-    the data-parallel size — the method's structural limitation on a large
-    mesh, now actually engaged because the trainer passes ``dp_size``."""
+    """GPT-3-style batch warmup — the related work the paper compares
+    against (§5.1) and finds provides *no* stability benefit: start at
+    ``start_batch`` and grow linearly in tokens to the full batch over
+    ``warmup_tokens``.  The batch must be a multiple of the data-parallel
+    size, so on a large mesh the warmup is quantized coarsely (vs SLW's
+    fixed "multiple of 8/128", which is independent of the mesh)."""
 
     name = "batch_warmup"
 
     def __init__(self, cfg: BatchWarmupConfig, full_batch: int,
                  dp_size: int = 1):
-        self.warmup = BatchWarmup(cfg, full_batch, dp_size=dp_size)
+        self.cfg = cfg
+        self.full_batch = full_batch
+        self.dp_size = dp_size
 
     def plan(self, tele: StepTelemetry, plan: StepPlan) -> StepPlan:
-        plan.batch_size = min(plan.batch_size,
-                              self.warmup.batch_for_tokens(tele.tokens_seen))
+        cfg = self.cfg
+        frac = min(tele.tokens_seen / max(cfg.warmup_tokens, 1), 1.0)
+        raw = cfg.start_batch + frac * (self.full_batch - cfg.start_batch)
+        plan.batch_size = min(plan.batch_size, quantize_batch(
+            raw, self.dp_size, cfg.start_batch, self.full_batch))
         return plan
 
 
@@ -211,77 +262,6 @@ class LRScheduleRegulator(Regulator):
     def plan(self, tele: StepTelemetry, plan: StepPlan) -> StepPlan:
         plan.lr = lr_at(self.cfg, tele.step, tele.tokens_seen)
         return plan
-
-
-class GradNoiseBatchRegulator(Regulator):
-    """Adaptive batch sizing from gradient-norm noise (beyond-paper).
-
-    Tracks EMA mean/second-moment of the **raw pre-clip** gradient norm;
-    while the relative std exceeds ``noise_target`` (gradient estimates are
-    noisy, so more averaging pays for itself — the critical-batch-size
-    argument), grows the batch multiplicatively.  Monotone non-decreasing,
-    quantized to the data-parallel size, capped at the full batch.
-
-    The pre-clip contract matters: a *post*-clip norm saturates at the clip
-    limit whenever training clips persistently, so its relative std reads
-    ~0 and the regulator never grows — the global clip would erase exactly
-    the noise signal being regulated on.  ``StepTelemetry.grad_norm`` is
-    that raw norm (``clip_global_norm`` measures before scaling and reports
-    the post-clip value separately as ``grad_norm_clipped``); the
-    regression test pinning this is in ``tests/test_regulators.py``.
-
-    Superseded by the measured-noise-scale ``critical_batch`` kind
-    (``repro.gns.regulator``) when ``TrainConfig.gns`` is enabled.
-    """
-
-    name = "grad_noise_batch"
-
-    def __init__(self, spec: RegulatorSpec, full_batch: int, dp_size: int = 1):
-        self.spec = spec
-        self.full_batch = full_batch
-        self.dp_size = max(dp_size, 1)
-        self.batch = self._quantize(spec.min_batch or full_batch // 8)
-        self.ema_g = 0.0
-        self.ema_g2 = 0.0
-        self.n_obs = 0
-
-    def _quantize(self, b: float) -> int:
-        return quantize_batch(b, self.dp_size, self.dp_size, self.full_batch)
-
-    def plan(self, tele: StepTelemetry, plan: StepPlan) -> StepPlan:
-        plan.batch_size = min(plan.batch_size, self.batch)
-        return plan
-
-    def observe(self, tele: StepTelemetry, tokens_step: int) -> None:
-        g = tele.grad_norm  # raw pre-clip norm — see the class docstring
-        if not math.isfinite(g):
-            return
-        if self.n_obs == 0:
-            # seed at the first observation — zero-init EMAs would read as
-            # huge relative variance and trigger spurious growth
-            self.ema_g, self.ema_g2 = g, g * g
-        else:
-            a = 2.0 / (self.spec.noise_window + 1.0)
-            self.ema_g = (1 - a) * self.ema_g + a * g
-            self.ema_g2 = (1 - a) * self.ema_g2 + a * g * g
-        self.n_obs += 1
-        if self.n_obs < self.spec.noise_window:
-            return  # EMAs not warmed up yet
-        var = max(self.ema_g2 - self.ema_g ** 2, 0.0)
-        rel_std = math.sqrt(var) / max(self.ema_g, 1e-12)
-        if rel_std > self.spec.noise_target and self.batch < self.full_batch:
-            self.batch = self._quantize(
-                max(self.batch * self.spec.growth, self.batch + self.dp_size))
-
-    def state_dict(self) -> Dict[str, Any]:
-        return {"batch": self.batch, "ema_g": self.ema_g,
-                "ema_g2": self.ema_g2, "n_obs": self.n_obs}
-
-    def load_state_dict(self, d: Dict[str, Any]) -> None:
-        self.batch = int(d["batch"])
-        self.ema_g = float(d["ema_g"])
-        self.ema_g2 = float(d["ema_g2"])
-        self.n_obs = int(d["n_obs"])
 
 
 class VarianceLRThrottle(Regulator):
@@ -454,27 +434,13 @@ class RegulatorStack:
 # construction from config
 # ---------------------------------------------------------------------------
 
-def auto_specs(tc: TrainConfig) -> Tuple[RegulatorSpec, ...]:
-    """Back-compat derivation from the legacy configs: the enabled legacy
-    schedules compose (they no longer exclude each other) and the LR
-    schedule always runs."""
-    specs: List[RegulatorSpec] = []
-    if tc.slw.enabled:
-        specs.append(RegulatorSpec(kind="seqlen"))
-    if tc.batch_warmup.enabled:
-        specs.append(RegulatorSpec(kind="batch_warmup"))
-    specs.append(RegulatorSpec(kind="lr"))
-    return tuple(specs)
-
-
 def build_stack(tc: TrainConfig, *, dp_size: int = 1,
                 warmup_steps_hint: int = 0,
                 prefix_tokens: int = 0) -> RegulatorStack:
-    """Build the control plane for a TrainConfig.  ``tc.regulators`` is the
-    explicit stack; empty means :func:`auto_specs` (legacy derivation)."""
-    specs = tc.regulators or auto_specs(tc)
+    """Build the control plane for a TrainConfig: one regulator per entry of
+    ``tc.regulators``, in order."""
     regs: List[Regulator] = []
-    for spec in specs:
+    for spec in tc.regulators:
         if spec.kind == "seqlen":
             regs.append(SeqLenRegulator(
                 tc.slw, tc.seq_len, warmup_steps_hint=warmup_steps_hint,
@@ -484,9 +450,6 @@ def build_stack(tc: TrainConfig, *, dp_size: int = 1,
                                            dp_size=dp_size))
         elif spec.kind == "lr":
             regs.append(LRScheduleRegulator(tc.optimizer))
-        elif spec.kind == "grad_noise_batch":
-            regs.append(GradNoiseBatchRegulator(spec, tc.global_batch,
-                                                dp_size=dp_size))
         elif spec.kind == "var_lr_throttle":
             regs.append(VarianceLRThrottle(spec))
         elif spec.kind == "critical_batch":

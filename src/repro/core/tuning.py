@@ -15,6 +15,7 @@ The probe is injected as a callable so the same tuner drives tiny CPU runs
 """
 from __future__ import annotations
 
+import dataclasses
 from dataclasses import dataclass
 from typing import Callable, Dict, List, Sequence, Tuple
 
@@ -56,8 +57,7 @@ def tune_slw(probe: ProbeFn, base: SLWConfig, warmup_steps: int,
     trials: List[Tuple[int, int, bool]] = []
 
     def fluctuates(s0: int, t: int) -> bool:
-        cfg = base.replace_slw(start_seq_len=s0, duration_steps=t) \
-            if hasattr(base, "replace_slw") else _replace(base, s0, t)
+        cfg = dataclasses.replace(base, start_seq_len=s0, duration_steps=t)
         bad = significant_fluctuation(probe(cfg), fluctuation_threshold)
         trials.append((s0, t, bad))
         return bad
@@ -83,8 +83,3 @@ def tune_slw(probe: ProbeFn, base: SLWConfig, warmup_steps: int,
     return TuneResult(seqlen_s=seqlen_s, duration=best * warmup_steps,
                       trials=trials)
 
-
-def _replace(cfg: SLWConfig, s0: int, t: int) -> SLWConfig:
-    import dataclasses
-    return dataclasses.replace(cfg, start_seq_len=s0, duration_steps=t,
-                               enabled=True)

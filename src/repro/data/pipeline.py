@@ -3,8 +3,8 @@
 The paper's implementation pre-indexes raw text into full-length sequences
 once and lets the curriculum truncate per step (Section 4) — re-indexing per
 length would be prohibitive at 157B tokens.  This pipeline mirrors that: it
-always yields full-length ``(B, S)`` batches; `SLWCurriculum.apply` truncates
-or repacks them host-side.
+always yields full-length ``(B, S)`` batches; `RegulatorStack.apply`
+truncates or repacks them host-side.
 
 Determinism/elasticity: batch `step` is sequence indices
 ``[step*B_global + r] for r in rank's slice``, pure arithmetic over

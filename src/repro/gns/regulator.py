@@ -1,11 +1,10 @@
 """B_noise-measured batch warmup: the ``critical_batch`` regulator kind.
 
-``GradNoiseBatchRegulator`` (PR 3) grows the batch while the relative std
-of the *scalar gradient norm* is high — a single-replica proxy for the
-quantity that actually decides whether averaging pays: the gradient noise
-scale ``B_noise = tr(Sigma)/|G|^2``.  This regulator supersedes the proxy
-with the measured estimate (Lau et al., *Adaptive Batch Size Schedules*,
-argue batch schedules should track exactly this): warmup advances while
+The quantity that decides whether averaging pays is the gradient noise
+scale ``B_noise = tr(Sigma)/|G|^2``; a proxy such as the relative std of
+the scalar gradient norm only stands in for it.  This regulator uses the
+measured estimate (Lau et al., *Adaptive Batch Size Schedules*, argue
+batch schedules should track exactly this): warmup advances while
 ``B_noise > headroom * batch`` (noise dominates — a bigger batch converts
 almost 1:1 into fewer steps) and holds when the measured headroom is gone
 (the efficiency curve ``1/(1 + B_noise/B)`` has flattened; more batch
@@ -23,8 +22,8 @@ import math
 from typing import Any, Dict
 
 from repro.configs.base import GNSConfig
-from repro.core.batch_warmup import quantize_batch
-from repro.core.regulators import Regulator, StepPlan, StepTelemetry
+from repro.core.regulators import (Regulator, StepPlan, StepTelemetry,
+                                   quantize_batch)
 from repro.gns.estimator import GNSEstimator
 
 

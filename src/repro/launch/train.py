@@ -333,7 +333,7 @@ class Trainer:
         self._recovery_reg: Optional[RecoveryRegulator] = None
         self._ring_dir = ""
         if recovery is not None:
-            ladder = (self.stack["seqlen"].curriculum.ladder
+            ladder = (self.stack["seqlen"].ladder
                       if "seqlen" in self.stack else (tc.seq_len,))
             self._recovery_reg = RecoveryRegulator(ladder, recovery)
             self.stack.regulators.append(self._recovery_reg)
@@ -584,7 +584,7 @@ def build_config(args) -> TrainConfig:
     if args.vocab:
         cfg = cfg.replace(vocab_size=args.vocab)
     slw = SLWConfig(
-        enabled=args.slw, pacing=args.pacing, start_seq_len=args.start_seq,
+        pacing=args.pacing, start_seq_len=args.start_seq,
         duration_steps=args.duration, round_multiple=args.round_multiple,
         mode=args.slw_mode, max_buckets=args.max_buckets)
     opt = OptimizerConfig(
@@ -597,36 +597,28 @@ def build_config(args) -> TrainConfig:
         agc_clip=args.agc,
         telemetry_level=("per_leaf" if args.per_leaf_telemetry
                          else "scalar"))
-    bw = BatchWarmupConfig(enabled=args.batch_warmup,
-                           start_batch=max(args.batch // 8, 1),
+    bw = BatchWarmupConfig(start_batch=max(args.batch // 8, 1),
                            warmup_tokens=(args.tokens or args.steps
                                           * args.batch * args.seq) // 20)
     gns = GNSConfig(enabled=args.gns or args.gns_batch,
                     shards=args.gns_shards,
                     precursor_window=args.gns_precursor_window,
                     headroom=args.gns_headroom)
-    tc = TrainConfig(model=cfg, optimizer=opt, slw=slw, batch_warmup=bw,
-                     gns=gns,
-                     seq_len=args.seq, global_batch=args.batch,
-                     seed=args.seed, remat=args.remat,
-                     eval_interval=args.eval_interval,
-                     checkpoint_interval=args.ckpt_interval,
-                     checkpoint_dir=args.ckpt_dir)
-    # adaptive regulators opt in via the explicit stack: the auto-derived
-    # schedules first, the telemetry-driven ones after (order matters — the
-    # LR throttle multiplies the scheduled LR).
-    extra = []
-    if args.grad_noise_batch:
-        extra.append(RegulatorSpec(kind="grad_noise_batch"))
-    if args.gns_batch:
-        extra.append(RegulatorSpec(kind="critical_batch"))
-    if args.var_lr_throttle:
-        extra.append(RegulatorSpec(kind="var_lr_throttle"))
-    if extra:
-        from repro.core.regulators import auto_specs
-        tc = dataclasses.replace(tc,
-                                 regulators=auto_specs(tc) + tuple(extra))
-    return tc
+    # the stack is the switch: one entry per flag that is on, the
+    # schedules first and the telemetry-driven regulators after (order
+    # matters — the LR throttle multiplies the scheduled LR)
+    kinds = (("seqlen", args.slw), ("batch_warmup", args.batch_warmup),
+             ("lr", True), ("critical_batch", args.gns_batch),
+             ("var_lr_throttle", args.var_lr_throttle))
+    return TrainConfig(model=cfg, optimizer=opt, slw=slw, batch_warmup=bw,
+                       regulators=tuple(RegulatorSpec(kind)
+                                        for kind, on in kinds if on),
+                       gns=gns,
+                       seq_len=args.seq, global_batch=args.batch,
+                       seed=args.seed, remat=args.remat,
+                       eval_interval=args.eval_interval,
+                       checkpoint_interval=args.ckpt_interval,
+                       checkpoint_dir=args.ckpt_dir)
 
 
 def build_parser() -> argparse.ArgumentParser:
@@ -662,8 +654,7 @@ def build_parser() -> argparse.ArgumentParser:
                         "per-layer blame in regulators and recovery")
     p.add_argument("--slw", action="store_true")
     p.add_argument("--pacing", default="linear",
-                   choices=["linear", "root", "two_stage", "variance_gated",
-                            "constant"])
+                   choices=["linear", "root", "two_stage", "variance_gated"])
     p.add_argument("--start-seq", type=int, default=8)
     p.add_argument("--duration", type=int, default=0)
     p.add_argument("--round-multiple", type=int, default=8)
@@ -672,8 +663,6 @@ def build_parser() -> argparse.ArgumentParser:
                    choices=["truncate", "repack"])
     p.add_argument("--batch-warmup", action="store_true",
                    help="composes with --slw (the paper's joint recipe)")
-    p.add_argument("--grad-noise-batch", action="store_true",
-                   help="adaptive batch sizing from grad-norm noise")
     p.add_argument("--gns", action="store_true",
                    help="gradient-noise-scale measurement: per-shard grad "
                         "norms inside the jitted step -> unbiased B_noise "

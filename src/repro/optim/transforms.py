@@ -103,7 +103,7 @@ def clip_global_norm(max_norm: float, per_leaf_telemetry: bool = False
     signal regulators act on.  ``grad_norm_clipped`` is the post-clip
     norm (``gnorm * scale``); under sustained clipping it saturates at
     the limit, which is exactly why nothing downstream may regulate on
-    it (see ``GradNoiseBatchRegulator``)."""
+    it."""
 
     def update(updates, state, params, hyper):
         leaves = [jnp.sum(jnp.square(x.astype(jnp.float32)))

@@ -23,7 +23,6 @@ from typing import Deque, Dict, List, Tuple
 
 import numpy as np
 
-from repro.configs.base import SLWConfig
 from repro.core.pacing import bucket_ladder, quantize
 from repro.serve.types import GenerationResult, Request
 from repro.serve import sampling
@@ -115,11 +114,8 @@ class SchedulerConfig:
         return self.pack_budget or self.cache_len * max(self.prefill_batch, 1)
 
     def ladder(self) -> Tuple[int, ...]:
-        slw = SLWConfig(enabled=True, start_seq_len=self.min_prompt_bucket,
-                        end_seq_len=self.cache_len,
-                        round_multiple=self.round_multiple,
-                        max_buckets=self.max_buckets)
-        return bucket_ladder(slw, self.cache_len)
+        return bucket_ladder(self.min_prompt_bucket, self.cache_len,
+                             self.round_multiple, self.max_buckets)
 
 
 def prefill_split(prompt_len: int, ladder: Tuple[int, ...]) -> int:

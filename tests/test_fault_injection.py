@@ -28,7 +28,7 @@ def _tc(steps=12, seq=64, batch=4, ckpt_dir="", interval=4):
             lr=2e-3, min_lr=1e-5, schedule="token_cosine",
             warmup_steps=4, warmup_tokens=4 * batch * seq,
             total_steps=steps, total_tokens=steps * batch * seq),
-        slw=SLWConfig(enabled=True, pacing="linear", start_seq_len=8,
+        slw=SLWConfig(pacing="linear", start_seq_len=8,
                       duration_steps=steps // 2, round_multiple=8,
                       max_buckets=4),
         seq_len=seq, global_batch=batch, remat="none",

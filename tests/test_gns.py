@@ -394,8 +394,10 @@ def test_gns_sketch_shape_tracks_precursor_dim():
 # end-to-end: trainer wiring, jsonl round-trip, composed checkpoint/resume
 # ---------------------------------------------------------------------------
 
-def _e2e_tc(steps=16, seq=64, batch=8, gns=None, regulators=(), ckpt_dir="",
-            slw=False, interval=0):
+def _e2e_tc(steps=16, seq=64, batch=8, gns=None, regulators=None,
+            ckpt_dir="", slw=False, interval=0):
+    if regulators is None:
+        regulators = (RegulatorSpec("seqlen"),) * slw + (RegulatorSpec("lr"),)
     cfg = _model_cfg()
     return TrainConfig(
         model=cfg,
@@ -403,7 +405,7 @@ def _e2e_tc(steps=16, seq=64, batch=8, gns=None, regulators=(), ckpt_dir="",
             lr=1e-3, min_lr=1e-5, schedule="token_cosine", warmup_steps=4,
             warmup_tokens=4 * batch * seq, total_steps=steps,
             total_tokens=steps * batch * seq),
-        slw=SLWConfig(enabled=slw, pacing="linear", start_seq_len=8,
+        slw=SLWConfig(pacing="linear", start_seq_len=8,
                       duration_steps=steps // 2, round_multiple=8,
                       max_buckets=4),
         regulators=regulators,

@@ -324,7 +324,7 @@ def test_train_loop_flash_backend_no_nans():
     on this CPU container) completes without NaNs and its per-step losses
     match the blockwise backend to <=1e-3."""
     from repro.configs import get_arch, reduced
-    from repro.configs.base import OptimizerConfig, SLWConfig, TrainConfig
+    from repro.configs.base import OptimizerConfig, RegulatorSpec, TrainConfig
     from repro.launch.train import train
 
     def tc(backend):
@@ -334,7 +334,7 @@ def test_train_loop_flash_backend_no_nans():
             model=cfg,
             optimizer=OptimizerConfig(lr=1e-3, schedule="constant",
                                       total_steps=4, total_tokens=4 * 2 * 32),
-            slw=SLWConfig(enabled=False),
+            regulators=(RegulatorSpec("lr"),),
             seq_len=32, global_batch=2, remat="none", eval_interval=0)
 
     res_flash = train(tc("flash_interpret"), quiet=True)

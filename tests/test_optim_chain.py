@@ -319,9 +319,8 @@ def _blame_tc(steps, telemetry_level="per_leaf"):
                           telemetry_level=telemetry_level)
     tc = TrainConfig(model=cfg, optimizer=opt, seq_len=32, global_batch=4,
                      seed=0, eval_interval=0, checkpoint_interval=0)
-    from repro.core.regulators import auto_specs
     return dataclasses.replace(
-        tc, regulators=auto_specs(tc)
+        tc, regulators=tc.regulators
         + (RegulatorSpec(kind="var_lr_throttle"),))
 
 

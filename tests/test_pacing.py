@@ -9,8 +9,10 @@ import random
 
 import pytest
 
-from repro.configs.base import SLWConfig
+from repro.configs import get_arch
+from repro.configs.base import RegulatorSpec, SLWConfig, TrainConfig
 from repro.core import pacing
+from repro.core.regulators import StepTelemetry, build_stack
 
 try:
     import hypothesis.strategies as st
@@ -35,7 +37,6 @@ def _builtin_cases(n=96, seed=0):
         full = rng.choice(FULLS)
         s0 = rng.choice(STARTS)
         cfg = SLWConfig(
-            enabled=True,
             pacing=rng.choice(PACINGS),
             start_seq_len=min(s0, full),
             duration_steps=rng.randint(1, 50_000),
@@ -50,8 +51,13 @@ def _builtin_cases(n=96, seed=0):
 # invariant bodies (shared by both drivers)
 # ---------------------------------------------------------------------------
 
+def _ladder(cfg, full):
+    return pacing.bucket_ladder(cfg.start_seq_len, full, cfg.round_multiple,
+                                cfg.max_buckets)
+
+
 def _check_ladder_invariants(cfg, full):
-    ladder = pacing.bucket_ladder(cfg, full)
+    ladder = _ladder(cfg, full)
     assert len(ladder) <= cfg.max_buckets + 8  # geometric prefix allowance
     assert ladder == tuple(sorted(set(ladder)))
     # round-*down* semantics: the smallest bucket never exceeds the
@@ -71,7 +77,7 @@ def _check_seqlen_bounds(cfg, full, step):
 def _check_monotone_nondecreasing(cfg, full):
     if cfg.pacing == "two_stage":
         return  # discrete jump is monotone by construction, tested below
-    ladder = pacing.bucket_ladder(cfg, full)
+    ladder = _ladder(cfg, full)
     prev = 0
     for t in range(0, cfg.duration_steps + 10,
                    max(cfg.duration_steps // 50, 1)):
@@ -94,7 +100,6 @@ if HAVE_HYPOTHESIS:
         full = draw(st.sampled_from(FULLS))
         s0 = draw(st.sampled_from(STARTS))
         return SLWConfig(
-            enabled=True,
             pacing=draw(st.sampled_from(PACINGS)),
             start_seq_len=min(s0, full),
             duration_steps=draw(st.integers(1, 50_000)),
@@ -166,7 +171,7 @@ def test_non_multiple_start_keeps_rounded_anchor():
     smallest bucket 16)."""
     cfg = SLWConfig(start_seq_len=12, duration_steps=100, round_multiple=8,
                     max_buckets=16)
-    ladder = pacing.bucket_ladder(cfg, 256)
+    ladder = _ladder(cfg, 256)
     assert ladder[0] == 8
     assert pacing.seqlen_at(cfg, 0, 256) <= 12
 
@@ -178,7 +183,15 @@ def test_two_stage_is_shortformer():
     assert pacing.raw_seqlen(cfg, 1000, 1024) == 1024
 
 
-def test_disabled_is_constant():
-    cfg = SLWConfig(enabled=False)
+def test_stack_without_seqlen_plans_full_length():
+    """SLW is off exactly when the stack has no seqlen regulator: every
+    step then plans the full length, whatever ``TrainConfig.slw`` says."""
+    from repro.configs import get_arch
+    from repro.configs.base import RegulatorSpec, TrainConfig
+    from repro.core.regulators import StepTelemetry, build_stack
+    tc = TrainConfig(model=get_arch("gpt2-117m").model, seq_len=2048,
+                     slw=SLWConfig(start_seq_len=8, duration_steps=100),
+                     regulators=(RegulatorSpec("lr"),))
+    stack = build_stack(tc)
     for t in (0, 10, 10_000):
-        assert pacing.seqlen_at(cfg, t, 2048) == 2048
+        assert stack.plan(StepTelemetry(step=t)).seq_len == 2048

@@ -9,16 +9,19 @@ from repro.configs.base import (BatchWarmupConfig, OptimizerConfig,
                                 RegulatorSpec, SLWConfig, TrainConfig)
 from repro.configs import get_arch, reduced
 from repro.core import pacing
-from repro.core.batch_warmup import BatchWarmup
-from repro.core.regulators import (ControllerState, GradNoiseBatchRegulator,
-                                   StepPlan, StepTelemetry,
-                                   VarianceLRThrottle, auto_specs,
-                                   build_stack, predict_trajectory)
+from repro.core.regulators import (ControllerState, StepPlan, StepTelemetry,
+                                   VarianceLRThrottle, build_stack,
+                                   predict_trajectory)
+from repro.launch.train import build_config, build_parser
 from repro.optim import lr_at
 
 
 def _tc(slw=True, batch_warmup=True, steps=40, seq=128, batch=8,
-        regulators=()):
+        regulators=None):
+    if regulators is None:
+        kinds = (("seqlen",) * slw + ("batch_warmup",) * batch_warmup
+                 + ("lr",))
+        regulators = tuple(RegulatorSpec(k) for k in kinds)
     cfg = reduced(get_arch("gpt2-117m").model).replace(vocab_size=256)
     return TrainConfig(
         model=cfg,
@@ -26,9 +29,9 @@ def _tc(slw=True, batch_warmup=True, steps=40, seq=128, batch=8,
             lr=2e-3, min_lr=1e-5, schedule="token_cosine", warmup_steps=8,
             warmup_tokens=8 * batch * seq, total_steps=steps,
             total_tokens=steps * batch * seq),
-        slw=SLWConfig(enabled=slw, start_seq_len=8, duration_steps=steps // 2,
+        slw=SLWConfig(start_seq_len=8, duration_steps=steps // 2,
                       round_multiple=8, max_buckets=5),
-        batch_warmup=BatchWarmupConfig(enabled=batch_warmup, start_batch=2,
+        batch_warmup=BatchWarmupConfig(start_batch=2,
                                        warmup_tokens=steps * batch * seq // 4),
         regulators=regulators,
         seq_len=seq, global_batch=batch, remat="none", eval_interval=0)
@@ -38,18 +41,28 @@ def _tc(slw=True, batch_warmup=True, steps=40, seq=128, batch=8,
 # stack construction + composition
 # ---------------------------------------------------------------------------
 
-def test_auto_specs_compose_legacy_configs():
-    kinds = [s.kind for s in auto_specs(_tc(slw=True, batch_warmup=True))]
-    assert kinds == ["seqlen", "batch_warmup", "lr"]
-    kinds = [s.kind for s in auto_specs(_tc(slw=False, batch_warmup=False))]
-    assert kinds == ["lr"]
+@pytest.mark.parametrize("flags,kinds", [
+    ([], ["lr"]),
+    (["--slw"], ["seqlen", "lr"]),
+    (["--batch-warmup"], ["batch_warmup", "lr"]),
+    (["--slw", "--batch-warmup"], ["seqlen", "batch_warmup", "lr"]),
+    (["--var-lr-throttle", "--gns-batch", "--slw"],
+     ["seqlen", "lr", "critical_batch", "var_lr_throttle"]),
+    (["--slw", "--batch-warmup", "--gns-batch", "--var-lr-throttle"],
+     ["seqlen", "batch_warmup", "lr", "critical_batch", "var_lr_throttle"]),
+])
+def test_cli_flags_build_the_regulator_tuple(flags, kinds):
+    """Each flag turns on exactly its regulator, in one fixed stack order
+    (schedules first; the LR throttle multiplies the scheduled LR)."""
+    tc = build_config(build_parser().parse_args(flags))
+    assert [s.kind for s in tc.regulators] == kinds
+    assert [r.name for r in build_stack(tc).regulators] == kinds
 
 
-def test_explicit_specs_override_auto():
-    tc = _tc(slw=True, batch_warmup=True,
-             regulators=(RegulatorSpec(kind="lr"),))
-    stack = build_stack(tc)
-    assert "seqlen" not in stack and "lr" in stack
+def test_default_stack_is_slw_and_lr():
+    """A TrainConfig that names no stack runs SLW and the LR schedule."""
+    tc = TrainConfig(model=_tc().model)
+    assert [r.name for r in build_stack(tc).regulators] == ["seqlen", "lr"]
 
 
 def test_unknown_kind_raises():
@@ -59,17 +72,20 @@ def test_unknown_kind_raises():
 
 
 def test_composed_plan_matches_individual_schedules():
-    """The stack's joint plan == each schedule computed standalone."""
+    """The stack's joint plan == each schedule's formula: the pacing
+    function on the bucket ladder, GPT-3's linear batch ramp in tokens
+    (rounded down, never below its start), the LR schedule."""
     tc = _tc(slw=True, batch_warmup=True)
     stack = build_stack(tc, warmup_steps_hint=tc.optimizer.warmup_steps)
-    bw = BatchWarmup(tc.batch_warmup, tc.global_batch)
-    ladder = pacing.bucket_ladder(tc.slw, tc.seq_len)
+    bw, full = tc.batch_warmup, tc.global_batch
     tokens = 0
     for step in range(30):
         plan = stack.plan(StepTelemetry(step=step, tokens_seen=tokens))
         assert plan.seq_len == pacing.seqlen_at(
-            tc.slw, step, tc.seq_len, tc.optimizer.warmup_steps, ladder)
-        assert plan.batch_size == bw.batch_for_tokens(tokens)
+            tc.slw, step, tc.seq_len, tc.optimizer.warmup_steps)
+        ramp = bw.start_batch + min(tokens / bw.warmup_tokens, 1.0) * (
+            full - bw.start_batch)
+        assert plan.batch_size == min(max(int(ramp), bw.start_batch), full)
         assert plan.lr == pytest.approx(lr_at(tc.optimizer, step, tokens))
         t_step = plan.seq_len * plan.batch_size
         stack.observe(StepTelemetry(step=step, tokens_seen=tokens), t_step)
@@ -120,10 +136,10 @@ def test_predict_trajectory_variance_gated_reaches_full():
 def test_dp_size_wired_into_batch_warmup():
     tc = _tc(slw=False, batch_warmup=True, batch=32)
     tc = dataclasses.replace(
-        tc, batch_warmup=BatchWarmupConfig(enabled=True, start_batch=4,
+        tc, batch_warmup=BatchWarmupConfig(start_batch=4,
                                            warmup_tokens=10_000))
     stack = build_stack(tc, dp_size=8)
-    assert stack["batch_warmup"].warmup.dp_size == 8
+    assert stack["batch_warmup"].dp_size == 8
     for tokens in (0, 2_000, 5_000, 9_000, 50_000):
         plan = stack.plan(StepTelemetry(step=0, tokens_seen=tokens))
         assert plan.batch_size % 8 == 0
@@ -132,27 +148,6 @@ def test_dp_size_wired_into_batch_warmup():
 # ---------------------------------------------------------------------------
 # adaptive regulators (beyond-paper scenario clients)
 # ---------------------------------------------------------------------------
-
-def test_grad_noise_batch_grows_only_under_noise():
-    spec = RegulatorSpec(kind="grad_noise_batch", min_batch=4,
-                         noise_window=4, noise_target=0.2, growth=2.0)
-    reg = GradNoiseBatchRegulator(spec, full_batch=64, dp_size=4)
-    assert reg.batch == 4
-    # calm gradients: batch must hold
-    for i in range(20):
-        reg.observe(StepTelemetry(step=i, grad_norm=1.0), 0)
-    assert reg.batch == 4
-    # noisy gradients: batch grows, stays a dp multiple, caps at full
-    for i in range(40):
-        reg.observe(StepTelemetry(step=i, grad_norm=1.0 if i % 2 else 8.0), 0)
-    assert reg.batch > 4
-    assert reg.batch % 4 == 0
-    assert reg.batch <= 64
-    # NaN grad norms are ignored, not folded into the EMAs
-    before = (reg.ema_g, reg.n_obs)
-    reg.observe(StepTelemetry(step=99, grad_norm=float("nan")), 0)
-    assert (reg.ema_g, reg.n_obs) == before
-
 
 def test_var_lr_throttle_backs_off_and_recovers():
     spec = RegulatorSpec(kind="var_lr_throttle", gate=2.0, floor=0.1,
@@ -195,7 +190,6 @@ def test_controller_state_roundtrip_all_regulators():
     tc = _tc(regulators=(RegulatorSpec(kind="seqlen"),
                          RegulatorSpec(kind="batch_warmup"),
                          RegulatorSpec(kind="lr"),
-                         RegulatorSpec(kind="grad_noise_batch"),
                          RegulatorSpec(kind="var_lr_throttle")))
     stack = build_stack(tc)
     # advance everything off its initial state
@@ -211,8 +205,7 @@ def test_controller_state_roundtrip_all_regulators():
     stack2 = build_stack(tc)
     stack2.load_controller_state(cs2)
     assert cs2.step == 12 and cs2.tokens_seen == 12 * 256
-    for name in ("seqlen", "batch_warmup", "lr", "grad_noise_batch",
-                 "var_lr_throttle"):
+    for name in ("seqlen", "batch_warmup", "lr", "var_lr_throttle"):
         assert stack2[name].state_dict() == stack[name].state_dict()
     # the restored stack plans identically
     tele = StepTelemetry(step=12, tokens_seen=12 * 256)
@@ -242,19 +235,37 @@ def test_legacy_host_state_migrates():
     assert migrate_host_state(host) is host
 
 
-def test_grad_noise_batch_reads_raw_preclip_norm():
-    """Regression for the pre-clip contract: under persistent clipping the
-    post-clip norm saturates at the limit (relative std ~0), which would
-    permanently starve the growth trigger.  The regulator must consume the
-    raw `grad_norm`, not `grad_norm_clipped`."""
-    spec = RegulatorSpec(kind="grad_noise_batch", min_batch=4,
-                         noise_window=4, noise_target=0.2, growth=2.0)
-    reg = GradNoiseBatchRegulator(spec, full_batch=64, dp_size=4)
-    for i in range(40):
-        # every step clips: the clipped norm is pinned at the limit while
-        # the raw norm is noisy — exactly the signal being regulated on
-        reg.observe(StepTelemetry(step=i,
-                                  grad_norm=1.0 if i % 2 else 8.0,
-                                  grad_norm_clipped=1.0), 0)
-    assert reg.batch > 4, \
-        "regulator starved by the saturated post-clip norm"
+_GATE_SLOT = {"step": 6, "tokens_seen": 4096, "gate_level": 2,
+              "var_trailing": 1.0}
+
+
+@pytest.mark.parametrize("host", [
+    # a checkpoint or ring snapshot whose seqlen slot still carries the
+    # counters the regulator used to keep itself
+    {"controller": {"step": 6, "tokens_seen": 4096,
+                    "regulators": {"seqlen": dict(_GATE_SLOT), "lr": {}},
+                    "tracker": {}}},
+    # a pre-regulator host dict: the curriculum's step is the only step
+    {"curriculum": dict(_GATE_SLOT), "tokens_seen": 4096, "tracker": {}},
+], ids=["controller", "curriculum"])
+def test_older_host_dicts_restore_and_plan_as_before(host):
+    from repro.checkpoint import migrate_host_state
+    args = build_parser().parse_args(
+        ["--batch", "16", "--seq", "256", "--slw", "--pacing",
+         "variance_gated", "--start-seq", "8", "--duration", "8",
+         "--max-buckets", "12", "--warmup", "4"])
+    stack = build_stack(build_config(args), warmup_steps_hint=args.warmup)
+    cs = ControllerState.from_host(migrate_host_state(host)["controller"])
+    assert (cs.step, cs.tokens_seen) == (6, 4096)
+    stack.load_controller_state(cs)
+    assert stack["seqlen"].state_dict() == {"gate_level": 2,
+                                            "var_trailing": 1.0}
+    seqs = []
+    for k in range(cs.step, cs.step + 4):
+        tele = StepTelemetry(step=k, tokens_seen=cs.tokens_seen,
+                             var_max=1.0, var_l1=1.0, grad_norm=1.0)
+        seqs.append(stack.plan(tele).seq_len)
+        stack.observe(tele, 0)
+    # the restored gate (rung 2 = 56) holds the envelope (176 at step 6)
+    # down, then climbs one rung per calm step — as before the change
+    assert seqs == [56, 80, 104, 128]

@@ -10,8 +10,8 @@ import pytest
 pytestmark = pytest.mark.slow
 
 from repro.configs import get_arch, reduced
-from repro.configs.base import (BatchWarmupConfig, OptimizerConfig, SLWConfig,
-                                TrainConfig)
+from repro.configs.base import (BatchWarmupConfig, OptimizerConfig,
+                                RegulatorSpec, SLWConfig, TrainConfig)
 from repro.launch.train import train
 
 
@@ -25,12 +25,13 @@ def _tc(slw: bool, steps=24, lr=2e-3, pacing="linear", batch_warmup=False,
             lr=lr, min_lr=1e-5, schedule=schedule, warmup_steps=6,
             warmup_tokens=6 * batch * seq, total_steps=steps,
             total_tokens=steps * batch * seq),
-        slw=SLWConfig(enabled=slw, pacing=pacing, start_seq_len=8,
+        slw=SLWConfig(pacing=pacing, start_seq_len=8,
                       duration_steps=steps // 2, round_multiple=8,
                       max_buckets=5),
         batch_warmup=BatchWarmupConfig(
-            enabled=batch_warmup, start_batch=2,
-            warmup_tokens=steps * batch * seq // 4),
+            start_batch=2, warmup_tokens=steps * batch * seq // 4),
+        regulators=tuple(RegulatorSpec(k) for k in (
+            ("seqlen",) * slw + ("batch_warmup",) * batch_warmup + ("lr",))),
         seq_len=seq, global_batch=batch, remat="none", eval_interval=10)
 
 

@@ -28,8 +28,7 @@ def _tiny_slw():
     return TrainConfig(
         model=reduced(get_arch("gpt2-117m").model).replace(vocab_size=256),
         optimizer=OptimizerConfig(lr=1e-3, warmup_steps=2, total_steps=8),
-        slw=SLWConfig(enabled=True, start_seq_len=8, duration_steps=4,
-                      max_buckets=2),
+        slw=SLWConfig(start_seq_len=8, duration_steps=4, max_buckets=2),
         seq_len=32, global_batch=4, remat="none", eval_interval=0,
         checkpoint_interval=0)
 

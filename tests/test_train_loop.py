@@ -8,10 +8,10 @@ import numpy as np
 import pytest
 
 from repro.configs import get_arch, reduced
-from repro.configs.base import (BatchWarmupConfig, OptimizerConfig, SLWConfig,
-                                TrainConfig)
+from repro.configs.base import (BatchWarmupConfig, OptimizerConfig,
+                                RegulatorSpec, SLWConfig, TrainConfig)
 from repro.core import pacing
-from repro.core.batch_warmup import BatchWarmup
+from repro.core.regulators import StepTelemetry
 from repro.distributed.fault_tolerance import (DrainSignal, StepWatchdog,
                                                TrainSupervisor)
 from repro.launch.train import Trainer, train
@@ -27,9 +27,11 @@ def _tc(steps=40, slw=True, lr=2e-3, seq=128, batch=8, ckpt_dir="",
             lr=lr, min_lr=1e-5, schedule="token_cosine",
             warmup_steps=8, warmup_tokens=8 * batch * seq,
             total_steps=steps, total_tokens=steps * batch * seq),
-        slw=SLWConfig(enabled=slw, pacing=pacing, start_seq_len=8,
+        slw=SLWConfig(pacing=pacing, start_seq_len=8,
                       duration_steps=steps // 2, round_multiple=8,
                       max_buckets=buckets, mode=mode),
+        regulators=tuple(RegulatorSpec(k)
+                         for k in (("seqlen", "lr") if slw else ("lr",))),
         seq_len=seq, global_batch=batch, remat="none",
         eval_interval=0, checkpoint_interval=10, checkpoint_dir=ckpt_dir)
 
@@ -103,22 +105,25 @@ def _composed_tc(steps, ckpt_dir="", seq=128, batch=8):
     return dataclasses.replace(
         tc,
         slw=dataclasses.replace(tc.slw, duration_steps=12),
-        batch_warmup=BatchWarmupConfig(enabled=True, start_batch=2,
-                                       warmup_tokens=1000),
+        batch_warmup=BatchWarmupConfig(start_batch=2, warmup_tokens=1000),
+        regulators=(RegulatorSpec("seqlen"), RegulatorSpec("batch_warmup"),
+                    RegulatorSpec("lr")),
         checkpoint_interval=8)
 
 
 def _predict_composed(tc, n_steps, dp_size=1):
-    """Per-step (seqlen, batch, lr) from each schedule computed standalone
-    (the primitive modules, not the stack) — the oracle the composed run
-    must match."""
-    ladder = pacing.bucket_ladder(tc.slw, tc.seq_len)
-    bw = BatchWarmup(tc.batch_warmup, tc.global_batch, dp_size=dp_size)
+    """Per-step (seqlen, batch, lr) from each schedule's formula computed
+    standalone (the pacing functions, GPT-3's linear batch ramp rounded
+    down to the dp size, the LR schedule; not the stack) — the oracle the
+    composed run must match."""
+    bw, full = tc.batch_warmup, tc.global_batch
     tokens, rows = 0, []
     for i in range(n_steps):
         s = pacing.seqlen_at(tc.slw, i, tc.seq_len,
-                             tc.optimizer.warmup_steps, ladder)
-        b = bw.batch_for_tokens(tokens)
+                             tc.optimizer.warmup_steps)
+        raw = int(bw.start_batch + min(tokens / bw.warmup_tokens, 1.0)
+                  * (full - bw.start_batch))
+        b = min(max(raw - raw % dp_size, bw.start_batch, dp_size), full)
         rows.append((s, b, lr_at(tc.optimizer, i, tokens)))
         tokens += s * b
     return rows
@@ -159,17 +164,18 @@ def test_variance_gated_resume_roundtrip(tmp_path):
     tr1 = Trainer(tc, quiet=True)
     res1 = tr1.run()
     assert res1.steps == 10
-    saved = dataclasses.asdict(tr1.stack["seqlen"].curriculum.state)
+    saved = tr1.stack["seqlen"].state_dict()
     assert saved["gate_level"] > 0  # the gate actually advanced
     assert saved["var_trailing"] > 0.0
 
     tc2 = _tc(steps=20, pacing="variance_gated", ckpt_dir=d)
     tr2 = Trainer(tc2, quiet=True)
     assert tr2.resume() == 10
-    restored = dataclasses.asdict(tr2.stack["seqlen"].curriculum.state)
-    assert restored == saved
-    assert tr2.stack["seqlen"].curriculum.seqlen_for_step() == \
-        tr1.stack["seqlen"].curriculum.seqlen_for_step()  # same bucket
+    assert tr2.stack["seqlen"].state_dict() == saved
+    assert tr2.step == tr1.step
+    next_seq = [tr.stack.plan(StepTelemetry(step=tr.step)).seq_len
+                for tr in (tr1, tr2)]
+    assert next_seq[0] == next_seq[1]  # same bucket
     res2 = tr2.run()
     assert res2.steps == 20
     assert res2.seqlen_history[0] >= res1.seqlen_history[-1]
